@@ -6,9 +6,9 @@ headline metric; derived-only rows leave ``us_per_call`` empty in the
 CSV and ``null`` in the JSON) and writes the same rows to
 ``BENCH_fleet.json`` so the perf trajectory is trackable across PRs.
 ``--only table2,fleet`` with ``--steps 64`` is the CI smoke subset.
-``--cache-dir DIR`` turns on the persistent JAX compilation cache
-(``repro.core.aot``) so repeat runs skip XLA compilation of the fleet
-programs — the committed ``BENCH_fleet.json`` is generated that way.
+The persistent JAX compilation cache (``repro.core.aot``) is on, so
+repeat runs skip XLA compilation of the fleet programs.  A bench that
+raises prints an ``ERROR:`` row and the run exits non-zero.
 """
 
 from __future__ import annotations
@@ -23,6 +23,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import aot
 from repro.core import controller as ctl
 from repro.core import predictors as pred_mod
 from repro.core import voltage as volt
@@ -552,71 +553,6 @@ def bench_voltage_optimizer():
             ("voltage_opt/runtime_lookup", lookup_us, "runtime_path")]
 
 
-def _cold_probe(cache_dir: str) -> None:
-    """Child-process body for :func:`bench_cold` (``--cold-probe DIR``).
-
-    Runs the two cold paths — the 25-bin table build and the batched
-    fleet first call — in a *fresh* process with the persistent
-    compilation cache pointed at ``cache_dir``, and prints the seconds
-    as JSON.  The parent runs this twice against the same directory:
-    first with an empty cache (true cold), then again (warm: same trace
-    cost, compilation served from disk).
-    """
-    from repro.core import aot
-    aot.enable_compilation_cache(cache_dir)
-    plat = ctl.fpga_platform(ACCELERATORS["tabla"])
-    grids = volt.VoltageGrids.default()
-    levels = volt.bin_frequency_levels(25, 0.05)
-    t0 = time.perf_counter()
-    volt.build_operating_table(plat.delay_fn, plat.power_fn, levels,
-                               grids).power.block_until_ready()
-    t_table = time.perf_counter() - t0
-    platforms = [ctl.fpga_platform(ACCELERATORS[n])
-                 for n in ("tabla", "stripes")]
-    trace = _trace(min(N_STEPS, 256))
-    t0 = time.perf_counter()
-    ctl.compare_all_batched(platforms, trace)
-    t_fleet = time.perf_counter() - t0
-    print(json.dumps({"table_s": t_table, "fleet_s": t_fleet}))
-
-
-def bench_cold():
-    """Cold-path cost with the persistent compilation cache, cold vs warm.
-
-    Spawns two fresh interpreters against one just-created cache
-    directory: the first pays trace + XLA compile and populates the
-    cache, the second pays trace + disk hit.  The warm/cold ratio is the
-    ``--cache-dir`` payoff a user sees on their second-ever run.
-    """
-    import shutil
-    import subprocess
-    import tempfile
-    cache = tempfile.mkdtemp(prefix="repro-jax-cache-")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(root, "src"), env.get("PYTHONPATH")) if p)
-    cmd = [sys.executable, "-m", "benchmarks.run", "--cold-probe", cache,
-           "--steps", str(N_STEPS)]
-    try:
-        runs = []
-        for _ in range(2):
-            out = subprocess.run(cmd, cwd=root, env=env, check=True,
-                                 capture_output=True, text=True).stdout
-            runs.append(json.loads(out.strip().splitlines()[-1]))
-        cold, warm = runs
-        return [
-            ("cold/table_build_first_call", cold["table_s"] * 1e6,
-             f"warm_cache_us={warm['table_s'] * 1e6:.0f}"
-             f";speedup={cold['table_s'] / warm['table_s']:.1f}x"),
-            ("cold/fleet_first_call", cold["fleet_s"] * 1e6,
-             f"warm_cache_us={warm['fleet_s'] * 1e6:.0f}"
-             f";speedup={cold['fleet_s'] / warm['fleet_s']:.1f}x"),
-        ]
-    finally:
-        shutil.rmtree(cache, ignore_errors=True)
-
-
 def bench_composition():
     """Fleet-composition search: candidate mixes × scenarios, one sweep.
 
@@ -692,10 +628,10 @@ BENCHES = [bench_fleet, bench_table2, bench_fig4_workload_sweep,
            bench_fig12_per_accelerator_traces, bench_predictor,
            bench_hybrid, bench_campaign, bench_failure, bench_replay,
            bench_scheduler, bench_voltage_optimizer, bench_composition,
-           bench_cold, bench_tpu_serving]
+           bench_tpu_serving]
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> int:
     global N_STEPS
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--steps", type=int, default=1024,
@@ -707,24 +643,15 @@ def main(argv=None) -> None:
                     "defaults to BENCH_fleet.json for full default runs "
                     "and off for --only/--steps subsets (so smoke runs "
                     "don't clobber the tracked perf record)")
-    ap.add_argument("--cache-dir", type=str, default="",
-                    help="persistent JAX compilation-cache directory "
-                    "(repro.core.aot) — repeat runs skip XLA compilation")
-    ap.add_argument("--cold-probe", type=str, default="",
-                    help=argparse.SUPPRESS)  # bench_cold child entry point
     args = ap.parse_args(argv)
     N_STEPS = args.steps
-    if args.cold_probe:
-        _cold_probe(args.cold_probe)
-        return
-    if args.cache_dir:
-        from repro.core import aot
-        aot.enable_compilation_cache(args.cache_dir)
+    aot.enable_compilation_cache()
     only = [s for s in args.only.split(",") if s]
     if args.json is None:
         args.json = "" if (only or N_STEPS != 1024) else "BENCH_fleet.json"
 
     results = {}
+    failed = []
     print("name,us_per_call,derived")
     for bench in BENCHES:
         if only and not any(s in bench.__name__ for s in only):
@@ -737,6 +664,7 @@ def main(argv=None) -> None:
                 us_s = "" if us is None else f"{us:.1f}"
                 print(f"{name},{us_s},{derived}", flush=True)
         except Exception as e:  # noqa: BLE001
+            failed.append(bench.__name__)
             results[bench.__name__] = {"us_per_call": None,
                                        "derived":
                                        f"ERROR:{type(e).__name__}:{e}"}
@@ -747,7 +675,11 @@ def main(argv=None) -> None:
             json.dump({"steps": N_STEPS, "benches": results}, f, indent=1,
                       sort_keys=True)
         print(f"# wrote {args.json} ({len(results)} rows)", file=sys.stderr)
+    if failed:
+        print(f"# FAILED: {','.join(failed)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -30,6 +30,7 @@ import json
 import os
 import time
 
+from repro.core import aot
 from repro.core import controller as ctl
 from repro.core import scenarios as scn
 from repro.core import traces
@@ -93,14 +94,11 @@ def main(argv=None) -> int:
     ap.add_argument("--list-schedulers", action="store_true",
                     help="print the registered scheduler policies and exit")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--cache-dir", type=str, default="",
-                    help="persistent JAX compilation-cache directory "
-                    "(core.aot): repeat campaigns skip XLA compilation "
-                    "of the fleet programs entirely")
     ap.add_argument("--warm", action="store_true",
                     help="AOT-compile the two fleet programs for this "
-                    "campaign's shapes before running (populates "
-                    "--cache-dir at setup time, not first-use time)")
+                    "campaign's shapes before running (populates the "
+                    "compilation cache at setup time, not first-use "
+                    "time)")
     ap.add_argument("--json", type=str, default="",
                     help="write the campaign table to this path")
     ap.add_argument("--trace", type=str, default="",
@@ -186,12 +184,8 @@ def main(argv=None) -> int:
         names = tuple(scn.with_failure_model(s, args.failure_model).name
                       for s in base)
 
-    if args.cache_dir:
-        from repro.core import aot
-        print(f"# compilation cache: "
-              f"{aot.enable_compilation_cache(args.cache_dir)}")
+    print(f"# compilation cache: {aot.enable_compilation_cache()}")
     if args.warm:
-        from repro.core import aot
         from repro.core import characterization as char
         params = char.stack_platform_params([p.params for p in platforms])
         cfg = ctl.ControllerConfig(n_nodes=args.n_nodes,

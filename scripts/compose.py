@@ -9,7 +9,7 @@ per-scenario Pareto set over (mean power, QoS violation rate, cost).
   PYTHONPATH=src python scripts/compose.py --platforms tabla,stripes,tpu \
       --scenarios burse,diurnal --max-nodes 12 --budget-cost 16
   PYTHONPATH=src python scripts/compose.py --candidates 200 --steps 8192 \
-      --cache-dir ~/.cache/repro-jax --json compose.json
+      --json compose.json
 
 The candidate batch runs in two equal halves; the second half must hit
 the first half's compiled chunk program.  ``--fail-on-retrace`` (used by
@@ -24,6 +24,7 @@ import time
 
 from campaign import build_platforms  # sibling script, not a package
 
+from repro.core import aot
 from repro.core import composition as comp
 from repro.core import controller as ctl
 from repro.core import scenarios as scn
@@ -53,8 +54,6 @@ def main(argv=None) -> int:
                     help="drop candidates whose nominal watts exceed this")
     ap.add_argument("--pareto-top", type=int, default=8,
                     help="rows of each Pareto set to print")
-    ap.add_argument("--cache-dir", type=str, default="",
-                    help="persistent JAX compilation-cache directory")
     ap.add_argument("--warm", action="store_true",
                     help="AOT-compile the fleet programs up front")
     ap.add_argument("--fail-on-retrace", action="store_true",
@@ -64,10 +63,7 @@ def main(argv=None) -> int:
                     help="write the full result table to this path")
     args = ap.parse_args(argv)
 
-    if args.cache_dir:
-        from repro.core import aot
-        print(f"# compilation cache: "
-              f"{aot.enable_compilation_cache(args.cache_dir)}")
+    print(f"# compilation cache: {aot.enable_compilation_cache()}")
 
     platforms = build_platforms(args.platforms)
     scenario_names = tuple(s for s in args.scenarios.split(",") if s)
@@ -78,7 +74,6 @@ def main(argv=None) -> int:
                                     max_power_w=args.budget_watts)
 
     if args.warm:
-        from repro.core import aot
         from repro.core import characterization as char
         params = char.stack_platform_params([p.params for p in platforms])
         n_half = -(-cand.shape[0] // 2)

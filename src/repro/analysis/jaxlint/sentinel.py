@@ -8,14 +8,16 @@ per-test generalization of the two hand-rolled witnesses
 (``tests/test_fleet.py::*zero_retrace*`` and
 ``composition.retraces_second_half``).
 
-Mechanism: while active, the sentinel wraps JAX's jaxpr-creation hook
-(``jax._src.pjit._create_pjit_jaxpr``) with a counting memoized
-wrapper — every tracing-cache miss increments the counter, exactly the
-event the zero-retrace contract forbids after warmup.  It also
+Mechanism: while active, the sentinel listens to JAX's public
+monitoring events (``jax.monitoring``) and counts every
+``/jax/core/compile/jaxpr_trace_duration`` event — JAX records one per
+tracing-cache miss, exactly the event the zero-retrace contract forbids
+after warmup.  Traces made while JAX dispatches a single primitive
+eagerly (op-by-op, outside any jit) are not counted: JAX keeps those
+one-primitive programs in a bounded LRU, so a long test session evicts
+and re-traces them with no fault of the code under test.  It also
 snapshots the repo's own :func:`repro.core.controller.fleet_trace_counts`
-so failures name which fleet program retraced.  If the private hook
-moves in a future JAX, the sentinel degrades to the fleet counters
-alone (and says so in its report).
+so failures name which fleet program retraced.
 
 Usage (see ``pytest_plugin.py`` for the pytest marker wiring)::
 
@@ -30,10 +32,25 @@ Usage (see ``pytest_plugin.py`` for the pytest marker wiring)::
 
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional
+import sys
+from typing import Dict
 
-#: name of the private hook we wrap; kept in one place for the fallback
-_PJIT_HOOK = "_create_pjit_jaxpr"
+import jax
+
+#: monitoring event JAX records once per new jaxpr trace
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+
+
+def _in_eager_dispatch() -> bool:
+    """True inside JAX's op-by-op dispatch of one primitive
+    (``jax._src.dispatch.apply_primitive`` is on the stack)."""
+    frame = sys._getframe(2)
+    while frame is not None:
+        code = frame.f_code
+        if code.co_name == "apply_primitive" and "jax" in code.co_filename:
+            return True
+        frame = frame.f_back
+    return False
 
 
 def _fleet_counts() -> Dict[str, int]:
@@ -43,7 +60,7 @@ def _fleet_counts() -> Dict[str, int]:
         from repro.core import controller
         return controller.fleet_trace_counts()
     except Exception:  # jaxlint: disable=JL008
-        # optional signal only: the pjit counter is the primary witness
+        # optional signal only: the trace-event counter is the witness
         return {}
 
 
@@ -52,10 +69,7 @@ class RetraceSentinel:
     :meth:`stop` (``arm`` defaults to ``start`` time)."""
 
     def __init__(self) -> None:
-        self._count = [0]
-        self._original: Optional[Callable] = None
-        self._patched = False
-        self._ever_patched = False
+        self._count = 0
         self._active = False
         self._baseline = 0
         self._baseline_fleet: Dict[str, int] = {}
@@ -67,7 +81,7 @@ class RetraceSentinel:
         if self._active:
             raise RuntimeError("sentinel already started")
         self._active = True
-        self._patch()
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
         self.arm()
         self._armed_explicitly = False
         return self
@@ -84,19 +98,20 @@ class RetraceSentinel:
         though the swept program never retraced."""
         if not self._active:
             raise RuntimeError("sentinel not started")
-        self._baseline = self._count[0]
+        self._baseline = self._count
         self._baseline_fleet = _fleet_counts()
         self._armed_explicitly = True
 
     def stop(self) -> None:
-        self._unpatch()
+        if self._active:
+            jax.monitoring.unregister_event_duration_listener(self._on_event)
         self._active = False
 
     # -- results ------------------------------------------------------
 
     def delta(self) -> int:
         """New traces since the last :meth:`arm`."""
-        return self._count[0] - self._baseline
+        return self._count - self._baseline
 
     def fleet_delta(self) -> Dict[str, int]:
         now = _fleet_counts()
@@ -115,37 +130,10 @@ class RetraceSentinel:
         fleet = self.fleet_delta()
         if fleet:
             parts.append(f"fleet programs retraced: {fleet}")
-        if not self._ever_patched:
-            parts.append("(pjit hook unavailable in this JAX — counts "
-                         "reflect fleet_trace_counts() only)")
         return "; ".join(parts)
 
-    # -- patching -----------------------------------------------------
+    # -- listener -----------------------------------------------------
 
-    def _patch(self) -> None:
-        try:
-            from jax._src import linear_util as lu
-            from jax._src import pjit as pjit_lib
-        except ImportError:
-            return
-        original = getattr(pjit_lib, _PJIT_HOOK, None)
-        if original is None:
-            return
-        count = self._count
-
-        @lu.cache
-        def create_pjit_jaxpr_and_count(*args):
-            count[0] += 1
-            return original(*args)
-
-        self._original = original
-        setattr(pjit_lib, _PJIT_HOOK, create_pjit_jaxpr_and_count)
-        self._patched = True
-        self._ever_patched = True
-
-    def _unpatch(self) -> None:
-        if self._patched and self._original is not None:
-            from jax._src import pjit as pjit_lib
-            setattr(pjit_lib, _PJIT_HOOK, self._original)
-            self._patched = False
-            self._original = None
+    def _on_event(self, event: str, duration_secs: float, **kwargs) -> None:
+        if event == _TRACE_EVENT and not _in_eager_dispatch():
+            self._count += 1

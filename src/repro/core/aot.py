@@ -5,11 +5,13 @@ tracing and XLA-compiling the two fleet programs (the grid-sweep tables
 program and the streaming chunk program) — costs seconds per process.
 This module makes that cost a one-time, machine-wide expense:
 
-* :func:`enable_compilation_cache` points JAX's persistent compilation
-  cache at a directory (``scripts/campaign.py --cache-dir``,
-  ``scripts/compose.py --cache-dir``, the CI bench smoke); every XLA
-  compile after that is written to / served from disk, so a process that
-  re-runs a previously-seen program shape only pays the (cheap) trace.
+* :func:`enable_compilation_cache` turns on JAX's persistent
+  compilation cache (``chip_smoke.py``, ``scripts/campaign.py``,
+  ``scripts/compose.py`` and ``benchmarks/run.py`` call it at start-up);
+  every XLA compile after that is written to / served from disk, so a
+  process that re-runs a previously-seen program shape only pays the
+  (cheap) trace.  The directory is ``$JAX_COMPILATION_CACHE_DIR`` when
+  that is set, else the fixed ``<checkout>/.jax-cache``.
 * :func:`warm_fleet_programs` ahead-of-time ``jit(...).lower(...)
   .compile()``\\ s both fleet programs for a given fleet shape — at setup
   time, not first-use time — populating the in-memory executable *and*
@@ -24,6 +26,7 @@ Nothing here runs at import time: call sites opt in explicitly.
 from __future__ import annotations
 
 import os
+import pathlib
 import time
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -35,43 +38,86 @@ from repro.core import predictors as pred_mod
 from repro.core import characterization as char
 from repro.core import scheduler as sched_mod
 
-_CACHE_DIR: Optional[str] = None
+#: Cache directory used when ``JAX_COMPILATION_CACHE_DIR`` is unset: a
+#: fixed path in the checkout (gitignored), so it is the same on every run.
+DEFAULT_CACHE_DIR = str(pathlib.Path(__file__).resolve().parents[3]
+                        / ".jax-cache")
 
 
-def enable_compilation_cache(cache_dir: str) -> str:
-    """Point the JAX persistent compilation cache at ``cache_dir``.
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
 
-    Zeroes the min-compile-time / min-entry-size gates so the fleet
-    programs (sub-second compiles on CPU) are cached too.  Idempotent;
-    returns the directory.  The same directory can be shared across
+    If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and no
+    other directory is set here; otherwise the cache goes to the fixed
+    :data:`DEFAULT_CACHE_DIR`.  Zeroes the min-compile-time /
+    min-entry-size gates so the fleet programs (sub-second compiles on
+    CPU) are cached too.  Idempotent.  The directory is shared across
     processes and reused across runs — that is the point: the second
     process's "cold" call skips XLA compilation entirely.
     """
-    global _CACHE_DIR
-    cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = DEFAULT_CACHE_DIR
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    try:
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
-    except (AttributeError, ValueError):  # jaxlint: disable=JL008
-        # deliberate version-compat fallback: the flag only exists on
-        # newer jax; the core compilation cache works without it
-        pass
-    _CACHE_DIR = cache_dir
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
     return cache_dir
-
-
-def cache_dir() -> Optional[str]:
-    """The enabled cache directory, or None if never enabled here."""
-    return _CACHE_DIR
 
 
 def _abstract(tree):
     return jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.asarray(x).dtype),
         tree)
+
+
+def tables_program_args(params: char.PlatformParams,
+                        cfg: ctl.ControllerConfig,
+                        techniques: Sequence[str] = ctl.DEFAULT_TECHNIQUES
+                        ) -> Tuple:
+    """Abstract positional arguments of the grid-sweep tables program
+    (``controller._fleet_dvfs_tables_jit``) for one fleet: the same
+    ``_sweep_rows`` shapes :func:`~repro.core.controller.fleet_bin_tables`
+    feeds it."""
+    grids, _, row_masks, row_levels = ctl._sweep_rows(cfg, techniques)
+    return tuple(_abstract(x) for x in (params, row_masks, row_levels,
+                                        grids.core, grids.bram))
+
+
+def stream_program_args(cfg: ctl.ControllerConfig, k: int,
+                        chunk_size: int = 1024, n_tenants: int = 1
+                        ) -> Tuple:
+    """Abstract array arguments of the streaming chunk program
+    (``controller._fleet_stream_chunk_jit``) at fleet size ``k``, chunk
+    ``chunk_size`` and tenant width ``n_tenants`` — everything but the
+    static ``cfg`` / ``emit``, in call order."""
+    m = cfg.n_bins
+    c = max(1, int(chunk_size))
+    q = max(1, int(n_tenants))
+    f32 = jnp.float32
+    # Per-bin [K, M] fields, except the per-cell scalar headroom [K].
+    flat = ctl.BinTables(*[jax.ShapeDtypeStruct(
+        (k,) if f == "headroom" else (k, m), f32)
+        for f in ctl.BinTables._fields])
+
+    # state_spec is already abstract (no concrete state materializes on
+    # the cold path) — only the fleet axis K is prepended here.
+    def _cell_states(pcfg):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct((k,) + x.shape, x.dtype),
+            pred_mod.state_spec(pcfg))
+
+    spec = sched_mod.TenantSpec(*[jax.ShapeDtypeStruct((k, q), f32)
+                                  for _ in sched_mod.TenantSpec._fields])
+    return (flat, _cell_states(cfg.predictor),
+            _cell_states(cfg.avail_predictor),
+            jax.ShapeDtypeStruct((k, q), f32),
+            jax.ShapeDtypeStruct((k, q), f32),
+            jax.ShapeDtypeStruct((k, c, q), f32),
+            jax.ShapeDtypeStruct((k, c), f32),
+            jax.ShapeDtypeStruct((c,), jnp.bool_), spec,
+            jax.ShapeDtypeStruct((3,), f32))
 
 
 def warm_fleet_programs(params: char.PlatformParams,
@@ -93,50 +139,20 @@ def warm_fleet_programs(params: char.PlatformParams,
     :func:`enable_compilation_cache` is active.  Returns wall-clock
     seconds per program: ``{"tables_compile_s", "stream_compile_s"}``.
     """
-    n_p = int(params.watts_scale.shape[0])
-    m = cfg.n_bins
-
-    # Program 1: the grid-sweep tables program.
-    grids, _, row_masks, row_levels = ctl._sweep_rows(cfg, techniques)
     t0 = time.perf_counter()
     ctl._fleet_dvfs_tables_jit.lower(
-        _abstract(params), _abstract(row_masks), _abstract(row_levels),
-        _abstract(grids.core), _abstract(grids.bram)).compile()
+        *tables_program_args(params, cfg, techniques)).compile()
     t_tables = time.perf_counter() - t0
 
-    # Program 2: the streaming chunk program (keyed on (K, C) + cfg).
+    # The streaming chunk program is keyed on (K, C) + cfg.
     if fleet_shape is None:
-        fleet_shape = (n_p, len(techniques))
+        fleet_shape = (int(params.watts_scale.shape[0]), len(techniques))
     k = 1
     for dim in fleet_shape:
         k *= int(dim)
-    c = max(1, int(chunk_size))
-    f32 = jnp.float32
-    # Per-bin [K, M] fields, except the per-cell scalar headroom [K].
-    flat = ctl.BinTables(*[jax.ShapeDtypeStruct(
-        (k,) if f == "headroom" else (k, m), f32)
-        for f in ctl.BinTables._fields])
-    # state_spec is already abstract (no concrete state materializes on
-    # the cold path) — only the fleet axis K is prepended here.
-    def _cell_states(pcfg):
-        return jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct((k,) + x.shape, x.dtype),
-            pred_mod.state_spec(pcfg))
-
-    mstate = _cell_states(cfg.predictor)
-    astate = _cell_states(cfg.avail_predictor)
-    q = max(1, int(n_tenants))
-    spec = sched_mod.TenantSpec(*[jax.ShapeDtypeStruct((k, q), f32)
-                                  for _ in sched_mod.TenantSpec._fields])
-    run_cfg = ctl._runtime_cfg(cfg)
     t0 = time.perf_counter()
     ctl._fleet_stream_chunk_jit.lower(
-        flat, mstate, astate, jax.ShapeDtypeStruct((k, q), f32),
-        jax.ShapeDtypeStruct((k, q), f32),
-        jax.ShapeDtypeStruct((k, c, q), f32),
-        jax.ShapeDtypeStruct((k, c), f32),
-        jax.ShapeDtypeStruct((c,), jnp.bool_), spec,
-        jax.ShapeDtypeStruct((3,), f32), run_cfg,
-        tuple(emit)).compile()
+        *stream_program_args(cfg, k, chunk_size, n_tenants),
+        ctl._runtime_cfg(cfg), tuple(emit)).compile()
     t_stream = time.perf_counter() - t0
     return {"tables_compile_s": t_tables, "stream_compile_s": t_stream}

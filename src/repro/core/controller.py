@@ -812,9 +812,10 @@ def _fleet_dvfs_tables_jit(params: char.PlatformParams, masks: Array,
     node-count gear (the node axis rides the same masked sweep); returns
     an :class:`~repro.core.voltage.OperatingPoint` with [P, R, M] fields.
 
-    The sweep body is the fused ``kernels.grid_argmin`` op: Pallas on
-    TPU/GPU, its lax reference on CPU (both match the closure optimizer
-    to ≤ 1e-5 — ``tests/test_kernels_grid_argmin.py``).
+    The sweep body is the fused ``kernels.grid_argmin`` op: the Pallas
+    kernel when lowered for a TPU, its lax reference on any other
+    platform (both match the closure optimizer to ≤ 1e-5 —
+    ``tests/test_kernels_grid_argmin.py``).
     """
     _TRACE_COUNTS["tables"] += 1  # Python side effect → counts tracings only
     return grid_argmin_op(params, masks, levels, core_grid, bram_grid)

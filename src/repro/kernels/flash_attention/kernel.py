@@ -69,10 +69,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float,
 
     def body(j, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(j * block_kv, block_kv),
-                            slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(j * block_kv, block_kv),
-                            slice(None))).astype(jnp.float32)
+        k = k_ref[pl.ds(j * block_kv, block_kv), :].astype(jnp.float32)
+        v = v_ref[pl.ds(j * block_kv, block_kv), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if softcap is not None:

@@ -8,9 +8,9 @@ package fuses that sweep into one Pallas kernel:
   kernel.py — ``pl.pallas_call`` grid over (platform, row); the delay /
       power term library, technique mask, QoS timing predicate, and the
       per-level argmin all evaluate in VMEM as one [levels × grid] tile.
-  ops.py    — jit'd public ``grid_argmin``; Pallas on TPU/GPU,
-      the lax reference on CPU, interpret mode via
-      ``REPRO_GRID_ARGMIN=interpret`` (CI parity tests).
+  ops.py    — jit'd public ``grid_argmin``; the kernel when lowered
+      for a TPU, the lax reference elsewhere, interpret mode via
+      ``impl="interpret"`` (CI parity tests).
   ref.py    — ``grid_argmin_ref``: the pre-kernel vmap pyramid over
       ``voltage.optimize_point_params`` (single source of truth through
       ``voltage.masked_grid_argmin``).

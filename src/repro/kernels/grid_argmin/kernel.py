@@ -10,6 +10,11 @@ grid point — the whole §V synthesis sweep is a single fused pass with no
 
 Layout notes:
 
+* every operand carries a unit axis in front of its last dim (``[P, 1, D]``
+  term libraries, ``[R, 1, G]`` masks, ``[P, R, 1, M]`` outputs) and the
+  per-cell axes are squeezed out of the block, so each block's last two
+  dims equal the array's — the layout rule Mosaic (the TPU lowering)
+  enforces for blocks that are not (8, 128)-aligned;
 * the (C × B) grid is flattened row-major and lane-padded to ``G``
   (multiple of 128); padded lanes carry ``mask=False`` so they can never
   win the argmin;
@@ -86,10 +91,10 @@ def _grid_argmin_kernel(dl_weight, dl_vth, dl_alpha, dl_v0, dl_rail,
     pick = lambda x: jnp.sum(jnp.where(onehot, x[None, :], 0.0), axis=1)
     p_nom = dyn[g_nominal] * f + stat[g_nominal]
 
-    v_core_out[0, 0, :] = jnp.where(any_f, pick(vc), vc[g_nominal])
-    v_bram_out[0, 0, :] = jnp.where(any_f, pick(vb), vb[g_nominal])
-    power_out[0, 0, :] = jnp.where(any_f, jnp.min(masked, axis=1), p_nom)
-    feas_out[0, 0, :] = any_f.astype(jnp.float32)
+    v_core_out[0, :] = jnp.where(any_f, pick(vc), vc[g_nominal])
+    v_bram_out[0, :] = jnp.where(any_f, pick(vb), vb[g_nominal])
+    power_out[0, :] = jnp.where(any_f, jnp.min(masked, axis=1), p_nom)
+    feas_out[0, :] = any_f.astype(jnp.float32)
 
 
 def grid_argmin_fwd(params: char.PlatformParams, masks_flat: Array,
@@ -107,26 +112,33 @@ def grid_argmin_fwd(params: char.PlatformParams, masks_flat: Array,
     d = params.dl_weight.shape[1]
     t = params.pw_dyn.shape[1]
 
-    plat = lambda block: pl.BlockSpec(block, lambda p, r: (p, 0))
-    row = lambda block: pl.BlockSpec(block, lambda p, r: (r, 0))
-    shared = lambda block: pl.BlockSpec(block, lambda p, r: (0, 0))
-    out = pl.BlockSpec((1, 1, m), lambda p, r: (p, r, 0))
+    # [N, w] operands gain a unit axis ([N, 1, w]); the squeezed (None)
+    # leading block dim leaves the kernel a [1, w] ref whose block equals
+    # the array's last two dims.
+    plat = lambda w: pl.BlockSpec((None, 1, w), lambda p, r: (p, 0, 0))
+    row = lambda w: pl.BlockSpec((None, 1, w), lambda p, r: (r, 0, 0))
+    shared = pl.BlockSpec((1, g), lambda p, r: (0, 0))
+    out = pl.BlockSpec((None, None, 1, m), lambda p, r: (p, r, 0, 0))
+    unit = lambda x: x[:, None, :]
 
     kernel = functools.partial(_grid_argmin_kernel, g_nominal=g_nominal,
                                slack_eps=slack_eps)
-    shape = jax.ShapeDtypeStruct((n_p, n_r, m), jnp.float32)
-    return pl.pallas_call(
+    shape = jax.ShapeDtypeStruct((n_p, n_r, 1, m), jnp.float32)
+    outs = pl.pallas_call(
         kernel,
         grid=(n_p, n_r),
-        in_specs=[plat((1, d))] * 4 + [plat((1, d))]            # delay terms
-        + [plat((1, 1))]                                        # delay_mode
-        + [plat((1, t))] * 5                                    # power terms
-        + [row((1, g)), row((1, m))]                            # mask, levels
-        + [shared((1, g))] * 2,                                 # vc, vb
+        in_specs=[plat(d)] * 5                                  # delay terms
+        + [plat(1)]                                             # delay_mode
+        + [plat(t)] * 5                                         # power terms
+        + [row(g), row(m)]                                      # mask, levels
+        + [shared] * 2,                                         # vc, vb
         out_specs=[out] * 4,
         out_shape=[shape] * 4,
         interpret=interpret,
-    )(params.dl_weight, params.dl_vth, params.dl_alpha, params.dl_v0,
-      params.dl_rail, params.delay_mode.reshape(n_p, 1).astype(jnp.int32),
-      params.pw_rail, params.pw_v0, params.pw_dyn, params.pw_stat,
-      params.pw_kappa, masks_flat, levels, vc_flat, vb_flat)
+    )(*map(unit, (params.dl_weight, params.dl_vth, params.dl_alpha,
+                  params.dl_v0, params.dl_rail,
+                  params.delay_mode.reshape(n_p, 1).astype(jnp.int32),
+                  params.pw_rail, params.pw_v0, params.pw_dyn,
+                  params.pw_stat, params.pw_kappa, masks_flat, levels)),
+      vc_flat, vb_flat)
+    return tuple(x[:, :, 0] for x in outs)

@@ -1,10 +1,12 @@
 """Public fused grid-argmin op (jit'd wrapper with backend dispatch).
 
-``grid_argmin`` is the fleet table sweep's entry point: Pallas-compiled
-on TPU/GPU, the pure-lax reference on CPU (where tier-1 CI runs), and
-Pallas-in-interpret-mode on request (``impl="interpret"`` or
-``REPRO_GRID_ARGMIN=interpret``) so the kernel body itself is testable
-everywhere.  All implementations share
+``grid_argmin`` is the fleet table sweep's entry point.  By default the
+program picks its implementation by the platform it is lowered for: the
+Pallas kernel on TPU, the pure-lax reference elsewhere (the CPU, where
+tier-1 CI runs).  An explicit ``impl=`` is the only override:
+``"pallas"``, ``"ref"``, or ``"interpret"`` (the kernel body run by the
+Pallas interpreter, so it is testable on any host).  All implementations
+share
 :func:`repro.core.voltage.masked_grid_argmin` semantics — first-flat-
 index tie-break, nominal-corner fallback — and must agree to ≤ 1e-5.
 """
@@ -12,7 +14,6 @@ index tie-break, nominal-corner fallback — and must agree to ≤ 1e-5.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -23,19 +24,6 @@ from repro.kernels.grid_argmin.kernel import grid_argmin_fwd
 from repro.kernels.grid_argmin.ref import grid_argmin_ref  # noqa: F401
 
 Array = jax.Array
-
-#: Environment override for the implementation choice ("pallas",
-#: "interpret", or "ref") — handy for benchmarking the kernel body on a
-#: CPU host without touching call sites.
-_ENV_VAR = "REPRO_GRID_ARGMIN"
-
-
-def _default_impl() -> str:
-    env = os.environ.get(_ENV_VAR, "").strip().lower()
-    if env in ("pallas", "interpret", "ref"):
-        return env
-    return "pallas" if jax.default_backend() in ("tpu", "gpu") else "ref"
-
 
 def _pad_to(n: int, mult: int) -> int:
     return -(-n // mult) * mult
@@ -53,9 +41,17 @@ def grid_argmin(params: char.PlatformParams, masks: Array, levels: Array,
     ``core_grid``/``bram_grid`` the shared ascending voltage grids.
     Returns an :class:`~repro.core.voltage.OperatingPoint` with
     ``[P, R, M]`` fields.  jit-keyed on shapes only (zero-retrace
-    contract — see ``controller.fleet_trace_counts``).
+    contract — see ``controller.fleet_trace_counts``).  ``impl=None``
+    lowers the kernel for a TPU and the reference for any other
+    platform (``lax.platform_dependent``), so the choice follows the
+    device the program runs on, not the process's default backend.
     """
-    impl = _default_impl() if impl is None else impl
+    if impl is None:
+        return jax.lax.platform_dependent(
+            params, masks, levels, core_grid, bram_grid,
+            tpu=functools.partial(grid_argmin, slack_eps=slack_eps,
+                                  impl="pallas"),
+            default=functools.partial(grid_argmin_ref, slack_eps=slack_eps))
     if impl == "ref":
         return grid_argmin_ref(params, masks, levels, core_grid, bram_grid,
                                slack_eps=slack_eps)
