@@ -26,9 +26,12 @@ expected output.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import time
+
+import jax
 
 from repro.core import aot
 from repro.core import controller as ctl
@@ -52,6 +55,17 @@ def build_platforms(spec: str):
             raise SystemExit(f"unknown platform {name!r}; choose from "
                              f"{sorted(ACCELERATORS)} + ['tpu', 'all']")
     return plats
+
+
+def profiled(log_dir: str):
+    """A profiler trace into ``log_dir`` around what it wraps, or nothing
+    when ``log_dir`` is empty.  The Python tracer stays off: the program's
+    spans (``repro.core.spans``) name the host phases."""
+    if not log_dir:
+        return contextlib.nullcontext()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return jax.profiler.trace(log_dir, profiler_options=opts)
 
 
 def main(argv=None) -> int:
@@ -112,6 +126,9 @@ def main(argv=None) -> int:
                     "per control step (default: one sample per step)")
     ap.add_argument("--list-scenarios", action="store_true",
                     help="print the registered scenario library and exit")
+    ap.add_argument("--profile", type=str, default="",
+                    help="write a profiler trace of the timed run, with "
+                    "the program's host spans, under this directory")
     args = ap.parse_args(argv)
 
     # Validate trace flags up front — one-line errors beat the deep
@@ -199,15 +216,19 @@ def main(argv=None) -> int:
         print(f"# warmed fleet programs: tables {t['tables_compile_s']:.2f}s"
               f", stream {t['stream_compile_s']:.2f}s")
 
-    t0 = time.perf_counter()
-    out = scn.run_campaign(platforms, scenario_names=names,
-                           techniques=techniques, n_steps=args.steps,
-                           seed=args.seed, chunk_size=args.chunk,
-                           n_nodes=args.n_nodes, predictor=args.predictor,
-                           tenants=args.tenants or None,
-                           scheduler=args.scheduler,
-                           headroom_frac=args.headroom_frac)
-    dt = time.perf_counter() - t0
+    with profiled(args.profile):
+        t0 = time.perf_counter()
+        out = scn.run_campaign(platforms, scenario_names=names,
+                               techniques=techniques, n_steps=args.steps,
+                               seed=args.seed, chunk_size=args.chunk,
+                               n_nodes=args.n_nodes,
+                               predictor=args.predictor,
+                               tenants=args.tenants or None,
+                               scheduler=args.scheduler,
+                               headroom_frac=args.headroom_frac)
+        dt = time.perf_counter() - t0
+    if args.profile:
+        print(f"# profiler trace under {args.profile}")
     cells = len(platforms) * len(techniques) * len(out["scenarios"])
     tenant_note = (f", tenants={args.tenants}, scheduler={args.scheduler}"
                    if args.tenants else "")

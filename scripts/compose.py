@@ -22,7 +22,7 @@ import argparse
 import json
 import time
 
-from campaign import build_platforms  # sibling script, not a package
+from campaign import build_platforms, profiled  # sibling script
 
 from repro.core import aot
 from repro.core import composition as comp
@@ -61,6 +61,9 @@ def main(argv=None) -> int:
                     "any fleet program (CI contract)")
     ap.add_argument("--json", type=str, default="",
                     help="write the full result table to this path")
+    ap.add_argument("--profile", type=str, default="",
+                    help="write a profiler trace of the timed run, with "
+                    "the program's host spans, under this directory")
     args = ap.parse_args(argv)
 
     print(f"# compilation cache: {aot.enable_compilation_cache()}")
@@ -83,12 +86,15 @@ def main(argv=None) -> int:
             fleet_shape=(n_half, len(platforms), len(scenario_names)),
             chunk_size=min(args.chunk, args.steps))
 
-    t0 = time.perf_counter()
-    res = comp.search_fleet_composition(
-        platforms, cand, scenario_names, budget,
-        technique=args.technique, n_steps=args.steps,
-        chunk_size=args.chunk, seed=args.seed)
-    dt = time.perf_counter() - t0
+    with profiled(args.profile):
+        t0 = time.perf_counter()
+        res = comp.search_fleet_composition(
+            platforms, cand, scenario_names, budget,
+            technique=args.technique, n_steps=args.steps,
+            chunk_size=args.chunk, seed=args.seed)
+        dt = time.perf_counter() - t0
+    if args.profile:
+        print(f"# profiler trace under {args.profile}")
 
     n = res.candidates.shape[0]
     print(f"# {n} candidates ({res.n_rejected} over budget) × "

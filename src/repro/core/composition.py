@@ -52,6 +52,7 @@ import numpy as np
 from repro.core import characterization as char
 from repro.core import controller as ctl
 from repro.core import scenarios as scn
+from repro.core import spans
 
 #: Techniques whose per-node §V operating points do not depend on the
 #: fleet's node count (no node-count gears / active-set quantization).
@@ -154,90 +155,93 @@ def search_fleet_composition(
     ``retraces_second_half`` witnesses that the second half recompiled
     nothing.
     """
-    if technique not in COMPOSABLE_TECHNIQUES:
-        raise ValueError(
-            f"technique {technique!r} is not composition-safe: its "
-            "per-node operating points depend on the fleet's node count "
-            f"(choose from {COMPOSABLE_TECHNIQUES})")
-    budget = CompositionBudget() if budget is None else budget
-    counts = np.asarray(candidates, np.float32)
-    if counts.ndim != 2 or counts.shape[1] != len(platforms):
-        raise ValueError(f"candidates must be [N, {len(platforms)}] "
-                         f"node counts; got {counts.shape}")
-    if np.any(counts.sum(axis=1) <= 0):
-        raise ValueError("candidates must keep at least one node")
+    with spans.span(spans.ENTRY_INPUTS):
+        if technique not in COMPOSABLE_TECHNIQUES:
+            raise ValueError(
+                f"technique {technique!r} is not composition-safe: its "
+                "per-node operating points depend on the fleet's node count "
+                f"(choose from {COMPOSABLE_TECHNIQUES})")
+        budget = CompositionBudget() if budget is None else budget
+        counts = np.asarray(candidates, np.float32)
+        if counts.ndim != 2 or counts.shape[1] != len(platforms):
+            raise ValueError(f"candidates must be [N, {len(platforms)}] "
+                             f"node counts; got {counts.shape}")
+        if np.any(counts.sum(axis=1) <= 0):
+            raise ValueError("candidates must keep at least one node")
 
-    n_plat = len(platforms)
-    thr = (np.ones(n_plat, np.float32) if node_throughput is None
-           else np.asarray(node_throughput, np.float32))
-    cost_vec = (np.ones(n_plat, np.float32) if node_cost is None
-                else np.asarray(node_cost, np.float32))
-    params = char.stack_platform_params([p.params for p in platforms])
-    cfg = ctl.ControllerConfig(technique=technique, **cfg_kwargs)
-    node_nom_w = ctl.fleet_node_nominal_watts(params, cfg)     # [P]
+        n_plat = len(platforms)
+        thr = (np.ones(n_plat, np.float32) if node_throughput is None
+               else np.asarray(node_throughput, np.float32))
+        cost_vec = (np.ones(n_plat, np.float32) if node_cost is None
+                    else np.asarray(node_cost, np.float32))
+        params = char.stack_platform_params([p.params for p in platforms])
+        cfg = ctl.ControllerConfig(technique=technique, **cfg_kwargs)
+        node_nom_w = ctl.fleet_node_nominal_watts(params, cfg)     # [P]
 
-    # Budget gates (host-side, before anything compiles).
-    cand_cost = counts @ cost_vec
-    cand_nom_w = counts @ node_nom_w.astype(np.float32)
-    keep = np.ones(counts.shape[0], bool)
-    if budget.max_cost is not None:
-        keep &= cand_cost <= budget.max_cost + 1e-9
-    if budget.max_power_w is not None:
-        keep &= cand_nom_w <= budget.max_power_w + 1e-9
-    n_rejected = int((~keep).sum())
-    counts, cand_cost, cand_nom_w = (counts[keep], cand_cost[keep],
-                                     cand_nom_w[keep])
-    if counts.shape[0] == 0:
-        raise ValueError("no candidate passed the budget gates")
+        # Budget gates (host-side, before anything compiles).
+        cand_cost = counts @ cost_vec
+        cand_nom_w = counts @ node_nom_w.astype(np.float32)
+        keep = np.ones(counts.shape[0], bool)
+        if budget.max_cost is not None:
+            keep &= cand_cost <= budget.max_cost + 1e-9
+        if budget.max_power_w is not None:
+            keep &= cand_nom_w <= budget.max_power_w + 1e-9
+        n_rejected = int((~keep).sum())
+        counts, cand_cost, cand_nom_w = (counts[keep], cand_cost[keep],
+                                         cand_nom_w[keep])
+        if counts.shape[0] == 0:
+            raise ValueError("no candidate passed the budget gates")
 
     # One grid sweep builds every platform's per-node §V table [P, M];
     # per-candidate tables differ only in *values* (counts-scaled power,
     # counts-valued n_active), broadcast onto [half, P, N_scen, M].
     tabs = ctl.fleet_bin_tables(params, cfg, techniques=(technique,))
-    per_node = {f: jnp.asarray(getattr(tabs, f)[:, 0]) for f in tabs._fields}
+    with spans.span(spans.ENTRY_INPUTS):
+        per_node = {f: jnp.asarray(getattr(tabs, f)[:, 0]) for f in tabs._fields}
 
-    scen_names, scen_traces, scen_avail = scn.build_suite(
-        scenarios, n_steps=n_steps, n_nodes=cfg.n_nodes, seed=seed)
-    n_scen = len(scen_names)
-    # Scenario availability as a *fraction* of the configured fleet, so
-    # node-failure scenarios hit every candidate sub-fleet pro rata.
-    frac_avail = (scen_avail / float(cfg.n_nodes)).astype(np.float32)
+        scen_names, scen_traces, scen_avail = scn.build_suite(
+            scenarios, n_steps=n_steps, n_nodes=cfg.n_nodes, seed=seed)
+        n_scen = len(scen_names)
+        # Scenario availability as a *fraction* of the configured fleet, so
+        # node-failure scenarios hit every candidate sub-fleet pro rata.
+        frac_avail = (scen_avail / float(cfg.n_nodes)).astype(np.float32)
 
-    # Each sub-fleet of candidate c sees utilization u_t = w_t·ref/cap_c
-    # of its own peak (capacity-proportional demand split).
-    cap_c = counts @ thr                                       # [N]
-    scale = (budget.reference_nodes / cap_c).astype(np.float32)
+        # Each sub-fleet of candidate c sees utilization u_t = w_t·ref/cap_c
+        # of its own peak (capacity-proportional demand split).
+        cap_c = counts @ thr                                       # [N]
+        scale = (budget.reference_nodes / cap_c).astype(np.float32)
 
-    # Two equal halves: the second half must hit the compiled chunk
-    # program from the first — the zero-retrace witness.  Odd batches
-    # repeat the last candidate (dropped from the results below).
-    n_real = counts.shape[0]
-    if n_real % 2:
-        counts = np.concatenate([counts, counts[-1:]])
-        scale = np.concatenate([scale, scale[-1:]])
-    half = counts.shape[0] // 2
+        # Two equal halves: the second half must hit the compiled chunk
+        # program from the first — the zero-retrace witness.  Odd batches
+        # repeat the last candidate (dropped from the results below).
+        n_real = counts.shape[0]
+        if n_real % 2:
+            counts = np.concatenate([counts, counts[-1:]])
+            scale = np.concatenate([scale, scale[-1:]])
+        half = counts.shape[0] // 2
 
     def run_half(counts_h: np.ndarray, scale_h: np.ndarray):
-        n_h = counts_h.shape[0]
-        cnt = jnp.asarray(counts_h)[:, :, None, None]          # [n,P,1,1]
-        shape = (n_h, n_plat, n_scen, cfg.n_bins)
+        with spans.span(spans.ENTRY_INPUTS):
+            n_h = counts_h.shape[0]
+            cnt = jnp.asarray(counts_h)[:, :, None, None]          # [n,P,1,1]
+            shape = (n_h, n_plat, n_scen, cfg.n_bins)
 
-        def cell(x):
-            return jnp.broadcast_to(x[None, :, None, :], shape)
+            def cell(x):
+                return jnp.broadcast_to(x[None, :, None, :], shape)
 
-        cells = ctl.BinTables(
-            capacity=cell(per_node["capacity"]),
-            power=cell(per_node["node_power"]) * cnt,
-            v_core=cell(per_node["v_core"]), v_bram=cell(per_node["v_bram"]),
-            f_rel=cell(per_node["f_rel"]),
-            n_active=jnp.broadcast_to(cnt, shape),
-            node_power=cell(per_node["node_power"]),
-            gated_power=jnp.zeros(shape),
-            headroom=jnp.zeros(shape[:-1]))
-        u = (scale_h[:, None, None, None]
-             * scen_traces[None, None, :, :]).astype(np.float32)
-        avail = (counts_h[:, :, None, None]
-                 * frac_avail[None, None, :, :]).astype(np.float32)
+            cells = ctl.BinTables(
+                capacity=cell(per_node["capacity"]),
+                power=cell(per_node["node_power"]) * cnt,
+                v_core=cell(per_node["v_core"]), v_bram=cell(per_node["v_bram"]),
+                f_rel=cell(per_node["f_rel"]),
+                n_active=jnp.broadcast_to(cnt, shape),
+                node_power=cell(per_node["node_power"]),
+                gated_power=jnp.zeros(shape),
+                headroom=jnp.zeros(shape[:-1]))
+            u = (scale_h[:, None, None, None]
+                 * scen_traces[None, None, :, :]).astype(np.float32)
+            avail = (counts_h[:, :, None, None]
+                     * frac_avail[None, None, :, :]).astype(np.float32)
         fs = ctl.simulate_fleet_stream(cells, u, cfg,
                                        chunk_size=chunk_size, avail=avail)
         return fs  # per-cell fields [n, P, N_scen]
@@ -248,30 +252,31 @@ def search_fleet_composition(
     after = ctl.fleet_trace_counts()
     retraces = sum(after[k] - before[k] for k in after)
 
-    def merge(field: str) -> np.ndarray:
-        return np.concatenate([np.asarray(getattr(fs_a, field)),
-                               np.asarray(getattr(fs_b, field))])[:n_real]
+    with spans.span(spans.ENTRY_RESULTS):
+        def merge(field: str) -> np.ndarray:
+            return np.concatenate([np.asarray(getattr(fs_a, field)),
+                                   np.asarray(getattr(fs_b, field))])[:n_real]
 
-    counts = counts[:n_real]
-    mean_power = merge("mean_power_w")                  # [N, P, S]
-    viol = merge("qos_violation_rate")
-    served = merge("served_fraction")
-    # Sub-fleet weights: capacity share (zero-count cells weigh nothing).
-    w = (counts * thr[None, :]) / (counts @ thr)[:, None]      # [N, P]
-    total_power = mean_power.sum(axis=1)                       # [N, S]
-    qos = np.einsum("np,nps->ns", w, viol)
-    served_w = np.einsum("np,nps->ns", w, served)
+        counts = counts[:n_real]
+        mean_power = merge("mean_power_w")                  # [N, P, S]
+        viol = merge("qos_violation_rate")
+        served = merge("served_fraction")
+        # Sub-fleet weights: capacity share (zero-count cells weigh nothing).
+        w = (counts * thr[None, :]) / (counts @ thr)[:, None]      # [N, P]
+        total_power = mean_power.sum(axis=1)                       # [N, S]
+        qos = np.einsum("np,nps->ns", w, viol)
+        served_w = np.einsum("np,nps->ns", w, served)
 
-    pareto: Dict[str, np.ndarray] = {}
-    for s, name in enumerate(scen_names):
-        objs = np.stack([total_power[:, s], qos[:, s], cand_cost], axis=1)
-        idx = np.flatnonzero(pareto_front(objs))
-        pareto[name] = idx[np.argsort(total_power[idx, s])]
+        pareto: Dict[str, np.ndarray] = {}
+        for s, name in enumerate(scen_names):
+            objs = np.stack([total_power[:, s], qos[:, s], cand_cost], axis=1)
+            idx = np.flatnonzero(pareto_front(objs))
+            pareto[name] = idx[np.argsort(total_power[idx, s])]
 
-    return CompositionResult(
-        platform_names=tuple(p.name for p in platforms),
-        scenario_names=tuple(scen_names),
-        candidates=counts.astype(np.int64), cost=cand_cost,
-        nominal_power_w=cand_nom_w, total_power_w=total_power,
-        qos_violation_rate=qos, served_fraction=served_w, pareto=pareto,
-        n_rejected=n_rejected, retraces_second_half=int(retraces))
+        return CompositionResult(
+            platform_names=tuple(p.name for p in platforms),
+            scenario_names=tuple(scen_names),
+            candidates=counts.astype(np.int64), cost=cand_cost,
+            nominal_power_w=cand_nom_w, total_power_w=total_power,
+            qos_violation_rate=qos, served_fraction=served_w, pareto=pareto,
+            n_rejected=n_rejected, retraces_second_half=int(retraces))

@@ -35,6 +35,7 @@ from repro.core import characterization as char
 from repro.core import pll as pll_mod
 from repro.core import predictors as pred_mod
 from repro.core import scheduler as sched_mod
+from repro.core import spans
 from repro.core import voltage as volt_mod
 from repro.core.accelerators import Accelerator
 from repro.kernels.grid_argmin import grid_argmin as grid_argmin_op
@@ -873,98 +874,99 @@ def fleet_bin_tables(params: char.PlatformParams, cfg: ControllerConfig,
     new accelerators of the same fleet shape never retraces
     (``fleet_trace_counts()["tables"]`` is the witness).
     """
-    m = cfg.n_bins
-    pll_watts = pll_standing_watts(cfg)
-    stall = pll_mod.stall_fraction(cfg.pll, cfg.tau)
-    n_p = params.watts_scale.shape[0]
+    with spans.span(spans.FLEET_TABLES):
+        m = cfg.n_bins
+        pll_watts = pll_standing_watts(cfg)
+        stall = pll_mod.stall_fraction(cfg.pll, cfg.tau)
+        n_p = params.watts_scale.shape[0]
 
-    per_tech: Dict[str, BinTables] = {}
-    dvfs = [t for t in techniques
-            if t not in ("nominal", "power_gating", "hybrid", "headroom")]
-    geared = [t for t in ("hybrid", "headroom") if t in techniques]
-    if dvfs or geared:
-        grids, levels, row_masks, row_levels = _sweep_rows(cfg, techniques)
-        if geared:
-            gears, f_node, gear_ok = _hybrid_gears(cfg)
-        pts = _fleet_dvfs_tables_jit(params, row_masks, row_levels,
-                                     grids.core, grids.bram)
-        node_w = pts.power * params.watts_scale[:, None, None]  # [P, R, M]
-        n_full = jnp.full((n_p, m), float(cfg.n_nodes))
-        zeros = jnp.zeros((n_p, m))
-        for i, t in enumerate(dvfs):
-            per_tech[t] = BinTables(
-                capacity=jnp.broadcast_to(levels * (1.0 - stall), (n_p, m)),
-                power=(node_w[:, i] + pll_watts) * cfg.n_nodes,
-                v_core=pts.v_core[:, i], v_bram=pts.v_bram[:, i],
-                f_rel=jnp.broadcast_to(levels, (n_p, m)), n_active=n_full,
-                node_power=node_w[:, i] + pll_watts, gated_power=zeros,
-                headroom=jnp.zeros(n_p))
-        # hybrid and headroom share the same G gear rows of the one
-        # sweep; headroom's reserve is a *runtime* policy, flagged to
-        # ``_headroom_bump`` by the headroom field — no extra compiled
-        # work, identical operating tables.
-        h_w = node_w[:, len(dvfs):]                           # [P, G, M]
-        for t in geared:
-            nom_w = _fleet_nominal_watts_jit(params)          # [P]
-            total = (gears[None, :, None] * (h_w + pll_watts)
-                     + (cfg.n_nodes - gears[None, :, None])
-                     * cfg.gated_power_frac * nom_w[:, None, None])
-            total = jnp.where(gear_ok[None], total, jnp.inf)
-            gi = jnp.argmin(total, axis=1)                    # [P, M]
+        per_tech: Dict[str, BinTables] = {}
+        dvfs = [t for t in techniques
+                if t not in ("nominal", "power_gating", "hybrid", "headroom")]
+        geared = [t for t in ("hybrid", "headroom") if t in techniques]
+        if dvfs or geared:
+            grids, levels, row_masks, row_levels = _sweep_rows(cfg, techniques)
+            if geared:
+                gears, f_node, gear_ok = _hybrid_gears(cfg)
+            pts = _fleet_dvfs_tables_jit(params, row_masks, row_levels,
+                                         grids.core, grids.bram)
+            node_w = pts.power * params.watts_scale[:, None, None]  # [P, R, M]
+            n_full = jnp.full((n_p, m), float(cfg.n_nodes))
+            zeros = jnp.zeros((n_p, m))
+            for i, t in enumerate(dvfs):
+                per_tech[t] = BinTables(
+                    capacity=jnp.broadcast_to(levels * (1.0 - stall), (n_p, m)),
+                    power=(node_w[:, i] + pll_watts) * cfg.n_nodes,
+                    v_core=pts.v_core[:, i], v_bram=pts.v_bram[:, i],
+                    f_rel=jnp.broadcast_to(levels, (n_p, m)), n_active=n_full,
+                    node_power=node_w[:, i] + pll_watts, gated_power=zeros,
+                    headroom=jnp.zeros(n_p))
+            # hybrid and headroom share the same G gear rows of the one
+            # sweep; headroom's reserve is a *runtime* policy, flagged to
+            # ``_headroom_bump`` by the headroom field — no extra compiled
+            # work, identical operating tables.
+            h_w = node_w[:, len(dvfs):]                           # [P, G, M]
+            for t in geared:
+                nom_w = _fleet_nominal_watts_jit(params)          # [P]
+                total = (gears[None, :, None] * (h_w + pll_watts)
+                         + (cfg.n_nodes - gears[None, :, None])
+                         * cfg.gated_power_frac * nom_w[:, None, None])
+                total = jnp.where(gear_ok[None], total, jnp.inf)
+                gi = jnp.argmin(total, axis=1)                    # [P, M]
 
-            def pick(x):  # gather the chosen gear from a [P, G, M] field
-                return jnp.take_along_axis(x, gi[:, None], axis=1)[:, 0]
+                def pick(x):  # gather the chosen gear from a [P, G, M] field
+                    return jnp.take_along_axis(x, gi[:, None], axis=1)[:, 0]
 
-            f_sel = pick(jnp.broadcast_to(f_node[None], h_w.shape))
-            n_sel = gears[gi]
-            per_tech[t] = BinTables(
-                capacity=(n_sel / cfg.n_nodes) * f_sel * (1.0 - stall),
-                power=pick(total),
-                v_core=pick(pts.v_core[:, len(dvfs):]),
-                v_bram=pick(pts.v_bram[:, len(dvfs):]),
-                f_rel=f_sel, n_active=n_sel,
-                node_power=pick(h_w) + pll_watts,
-                gated_power=jnp.broadcast_to(
-                    (cfg.gated_power_frac * nom_w)[:, None], (n_p, m)),
-                headroom=jnp.full(n_p, cfg.headroom_frac
-                                  if t == "headroom" else 0.0))
+                f_sel = pick(jnp.broadcast_to(f_node[None], h_w.shape))
+                n_sel = gears[gi]
+                per_tech[t] = BinTables(
+                    capacity=(n_sel / cfg.n_nodes) * f_sel * (1.0 - stall),
+                    power=pick(total),
+                    v_core=pick(pts.v_core[:, len(dvfs):]),
+                    v_bram=pick(pts.v_bram[:, len(dvfs):]),
+                    f_rel=f_sel, n_active=n_sel,
+                    node_power=pick(h_w) + pll_watts,
+                    gated_power=jnp.broadcast_to(
+                        (cfg.gated_power_frac * nom_w)[:, None], (n_p, m)),
+                    headroom=jnp.full(n_p, cfg.headroom_frac
+                                      if t == "headroom" else 0.0))
 
-    if "nominal" in techniques or "power_gating" in techniques:
-        node_w = _fleet_nominal_watts_jit(params)  # [P]
-        nom_vc = jnp.full((n_p, m), char.V_CORE_NOM)
-        nom_vb = jnp.full((n_p, m), char.V_BRAM_NOM)
-        ones = jnp.ones((n_p, m))
-        if "nominal" in techniques:
-            per_tech["nominal"] = BinTables(
-                capacity=ones,
-                power=jnp.broadcast_to(
-                    ((node_w + pll_watts) * cfg.n_nodes)[:, None], (n_p, m)),
-                v_core=nom_vc, v_bram=nom_vb, f_rel=ones,
-                n_active=jnp.full((n_p, m), float(cfg.n_nodes)),
-                node_power=jnp.broadcast_to((node_w + pll_watts)[:, None],
-                                            (n_p, m)),
-                gated_power=jnp.zeros((n_p, m)),
-                headroom=jnp.zeros(n_p))
-        if "power_gating" in techniques:
-            edges = (np.arange(m) + 1.0) / m
-            n_active = jnp.asarray(np.minimum(np.ceil(edges * cfg.n_nodes),
-                                              cfg.n_nodes), jnp.float32)
-            gated = ((cfg.n_nodes - n_active) * cfg.gated_power_frac
-                     * node_w[:, None])
-            per_tech["power_gating"] = BinTables(
-                capacity=jnp.broadcast_to(n_active / cfg.n_nodes, (n_p, m)),
-                power=n_active * (node_w[:, None] + pll_watts) + gated,
-                v_core=nom_vc, v_bram=nom_vb, f_rel=ones,
-                n_active=jnp.broadcast_to(n_active, (n_p, m)),
-                node_power=jnp.broadcast_to((node_w + pll_watts)[:, None],
-                                            (n_p, m)),
-                gated_power=jnp.broadcast_to(
-                    (cfg.gated_power_frac * node_w)[:, None], (n_p, m)),
-                headroom=jnp.zeros(n_p))
+        if "nominal" in techniques or "power_gating" in techniques:
+            node_w = _fleet_nominal_watts_jit(params)  # [P]
+            nom_vc = jnp.full((n_p, m), char.V_CORE_NOM)
+            nom_vb = jnp.full((n_p, m), char.V_BRAM_NOM)
+            ones = jnp.ones((n_p, m))
+            if "nominal" in techniques:
+                per_tech["nominal"] = BinTables(
+                    capacity=ones,
+                    power=jnp.broadcast_to(
+                        ((node_w + pll_watts) * cfg.n_nodes)[:, None], (n_p, m)),
+                    v_core=nom_vc, v_bram=nom_vb, f_rel=ones,
+                    n_active=jnp.full((n_p, m), float(cfg.n_nodes)),
+                    node_power=jnp.broadcast_to((node_w + pll_watts)[:, None],
+                                                (n_p, m)),
+                    gated_power=jnp.zeros((n_p, m)),
+                    headroom=jnp.zeros(n_p))
+            if "power_gating" in techniques:
+                edges = (np.arange(m) + 1.0) / m
+                n_active = jnp.asarray(np.minimum(np.ceil(edges * cfg.n_nodes),
+                                                  cfg.n_nodes), jnp.float32)
+                gated = ((cfg.n_nodes - n_active) * cfg.gated_power_frac
+                         * node_w[:, None])
+                per_tech["power_gating"] = BinTables(
+                    capacity=jnp.broadcast_to(n_active / cfg.n_nodes, (n_p, m)),
+                    power=n_active * (node_w[:, None] + pll_watts) + gated,
+                    v_core=nom_vc, v_bram=nom_vb, f_rel=ones,
+                    n_active=jnp.broadcast_to(n_active, (n_p, m)),
+                    node_power=jnp.broadcast_to((node_w + pll_watts)[:, None],
+                                                (n_p, m)),
+                    gated_power=jnp.broadcast_to(
+                        (cfg.gated_power_frac * node_w)[:, None], (n_p, m)),
+                    headroom=jnp.zeros(n_p))
 
-    return BinTables(*[jnp.stack([getattr(per_tech[t], f) for t in techniques],
-                                 axis=1)
-                       for f in BinTables._fields])
+        return BinTables(*[jnp.stack([getattr(per_tech[t], f) for t in techniques],
+                                     axis=1)
+                           for f in BinTables._fields])
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -1331,177 +1333,183 @@ def simulate_fleet_stream(tables: BinTables, traces: np.ndarray | Array,
     Matches the materialized path to float32 reduction accuracy (≤1e-5
     relative — see tests/test_fleet.py).
     """
-    # emit accepts TraceResult per-step names; internally _StepOut names
-    # one field differently ("violations" → "violation").
-    alias = {"violations": "violation"}
-    emit = tuple(emit)
-    emit_internal = tuple(alias.get(e, e) for e in emit)
-    for e, ei in zip(emit, emit_internal):
-        if ei not in _EMITTABLE:
-            per_step = tuple(f for f in TraceResult._fields
-                             if f not in ("mispredictions",
-                                          "final_predictor"))
-            raise ValueError(f"unknown emit field {e!r}; "
-                             f"choose from {per_step}")
-    lead = tables.capacity.shape[:-1]
-    k = int(np.prod(lead, dtype=np.int64)) if lead else 1
-    flat = BinTables(*[jnp.reshape(x, (k,) + x.shape[len(lead):])
-                       for x in tables])
-    # Keep traces/availability in their lead + (S, …) stride-0 broadcast
-    # form — a dense (K, S) reshape here would silently copy K·S floats
-    # (numpy cannot express it as a view), breaking the O(K) memory
-    # contract.  Only the per-chunk slices below ever materialize.
-    spec_in = tenant_spec if tenant_spec is not None \
-        else sched_mod.default_tenants(1)
-    t = spec_in.n_tenants
-    if tenant_spec is None:
-        # Aggregate workload: ride the tenant plane as a single default
-        # tenant — the trailing axis is a stride-0 numpy view.
-        traces = _broadcast_traces(np.asarray(traces), lead)[..., None]
-    else:
-        traces = _broadcast_tenant_traces(np.asarray(traces), lead, t)
-    s = traces.shape[-2]
-    avail_full = _broadcast_avail(avail, lead, cfg.n_nodes, s)
-    c = max(1, min(int(chunk_size), s))
-    scfg = cfg.scheduler if tenant_spec is not None \
-        else sched_mod.SCHEDULERS["none"]
-    sched_vals = sched_mod.scheduler_values(scfg)
-    # Normalize the static jit key: the technique only changed the
-    # tables, and the scheduler rides as values.
-    cfg = _runtime_cfg(cfg)
+    with spans.span(spans.STREAM_SETUP):
+        # emit accepts TraceResult per-step names; internally _StepOut names
+        # one field differently ("violations" → "violation").
+        alias = {"violations": "violation"}
+        emit = tuple(emit)
+        emit_internal = tuple(alias.get(e, e) for e in emit)
+        for e, ei in zip(emit, emit_internal):
+            if ei not in _EMITTABLE:
+                per_step = tuple(f for f in TraceResult._fields
+                                 if f not in ("mispredictions",
+                                              "final_predictor"))
+                raise ValueError(f"unknown emit field {e!r}; "
+                                 f"choose from {per_step}")
+        lead = tables.capacity.shape[:-1]
+        k = int(np.prod(lead, dtype=np.int64)) if lead else 1
+        flat = BinTables(*[jnp.reshape(x, (k,) + x.shape[len(lead):])
+                           for x in tables])
+        # Keep traces/availability in their lead + (S, …) stride-0 broadcast
+        # form — a dense (K, S) reshape here would silently copy K·S floats
+        # (numpy cannot express it as a view), breaking the O(K) memory
+        # contract.  Only the per-chunk slices below ever materialize.
+        spec_in = tenant_spec if tenant_spec is not None \
+            else sched_mod.default_tenants(1)
+        t = spec_in.n_tenants
+        if tenant_spec is None:
+            # Aggregate workload: ride the tenant plane as a single default
+            # tenant — the trailing axis is a stride-0 numpy view.
+            traces = _broadcast_traces(np.asarray(traces), lead)[..., None]
+        else:
+            traces = _broadcast_tenant_traces(np.asarray(traces), lead, t)
+        s = traces.shape[-2]
+        avail_full = _broadcast_avail(avail, lead, cfg.n_nodes, s)
+        c = max(1, min(int(chunk_size), s))
+        scfg = cfg.scheduler if tenant_spec is not None \
+            else sched_mod.SCHEDULERS["none"]
+        sched_vals = sched_mod.scheduler_values(scfg)
+        # Normalize the static jit key: the technique only changed the
+        # tables, and the scheduler rides as values.
+        cfg = _runtime_cfg(cfg)
 
-    mesh = shd.fleet_mesh() if shard else None
-    k_pad = k
-    if mesh is not None:
-        d = mesh.devices.size
-        k_pad = -(-k // d) * d
-    if k_pad != k:
-        # Pad the fleet axis so it divides the device count; padded cells
-        # replay cell 0 and are dropped from every result below.  The
-        # trace rows are padded per *chunk* (below), never as a dense
-        # [k_pad, S] array — the O(K·C) memory contract must survive
-        # sharding.
-        pad = [(0, k_pad - k)] + [(0, 0)] * (flat.capacity.ndim - 1)
-        flat = BinTables(*[jnp.pad(x, pad[:x.ndim], mode="edge")
-                           for x in flat])
-
-    spec = _flatten_tenant_spec(spec_in, lead, k, k_pad)
-    mstate = jax.tree.map(
-        lambda x: jnp.broadcast_to(x, (k_pad,) + x.shape),
-        pred_mod.init_state(cfg.predictor))
-    astate = jax.tree.map(
-        lambda x: jnp.broadcast_to(x, (k_pad,) + x.shape),
-        pred_mod.init_state(cfg.avail_predictor))
-    backlog = jnp.zeros((k_pad, t), jnp.float32)
-    place = jnp.zeros((k_pad, t), jnp.float32)
-    if mesh is not None:
-        rules = shd.fleet_rules(mesh)
-        flat = shd.shard_fleet(flat, rules)
-        mstate = shd.shard_fleet(mstate, rules)
-        astate = shd.shard_fleet(astate, rules)
-        backlog = shd.shard_fleet(backlog, rules)
-        place = shd.shard_fleet(place, rules)
-        spec = shd.shard_fleet(spec, rules)
-
-    power_sum = np.zeros(k_pad, np.float64)
-    viol_sum = np.zeros(k_pad, np.float64)
-    backlog_sum = np.zeros(k_pad, np.float64)
-    offered_sum = np.zeros(k_pad, np.float64)
-    avail_sum = np.zeros(k_pad, np.float64)
-    t_viol_sum = np.zeros((k_pad, t), np.float64)
-    t_starve_sum = np.zeros((k_pad, t), np.float64)
-    t_served_sum = np.zeros((k_pad, t), np.float64)
-    t_offered_sum = np.zeros((k_pad, t), np.float64)
-
-    def chunked(rows, s0, n_valid):
-        """One [k_pad, C] device chunk of a lead + (S,) row set.
-
-        ``rows`` may be a stride-0 broadcast; slicing the step axis keeps
-        the view, so only k·C elements materialize per chunk — never K·S.
-        """
-        raw = np.ascontiguousarray(rows[..., s0:s0 + c]).reshape((k, -1))
-        if n_valid < c:
-            raw = np.pad(raw, ((0, 0), (0, c - n_valid)))
-        if k_pad != k:
-            raw = np.concatenate(
-                [raw, np.broadcast_to(raw[:1], (k_pad - k, raw.shape[-1]))])
-        out = jnp.asarray(raw)
-        return shd.shard_fleet(out, rules) if mesh is not None else out
-
-    def chunked_plane(rows, s0, n_valid):
-        """One [k_pad, C, T] device chunk of the lead + (S, T) plane."""
-        raw = np.ascontiguousarray(
-            rows[..., s0:s0 + c, :]).reshape((k, -1, t))
-        if n_valid < c:
-            raw = np.pad(raw, ((0, 0), (0, c - n_valid), (0, 0)))
-        if k_pad != k:
-            raw = np.concatenate(
-                [raw, np.broadcast_to(raw[:1],
-                                      (k_pad - k,) + raw.shape[1:])])
-        out = jnp.asarray(raw)
-        return shd.shard_fleet(out, rules) if mesh is not None else out
-
-    # Healthy fleets have a constant all-n_nodes schedule: build its
-    # device chunk once and reuse it, instead of re-materializing and
-    # re-transferring an identical [k_pad, C] array every chunk.
-    # (Padded/invalid steps never escape — the valid mask gates the
-    # carry and emits are cut to n_valid — so one chunk fits all.)
-    av_const = None
-    if avail is None:
-        av_const = jnp.full((k_pad, c), jnp.float32(cfg.n_nodes))
+        mesh = shd.fleet_mesh() if shard else None
+        k_pad = k
         if mesh is not None:
-            av_const = shd.shard_fleet(av_const, rules)
+            d = mesh.devices.size
+            k_pad = -(-k // d) * d
+        if k_pad != k:
+            # Pad the fleet axis so it divides the device count; padded cells
+            # replay cell 0 and are dropped from every result below.  The
+            # trace rows are padded per *chunk* (below), never as a dense
+            # [k_pad, S] array — the O(K·C) memory contract must survive
+            # sharding.
+            pad = [(0, k_pad - k)] + [(0, 0)] * (flat.capacity.ndim - 1)
+            flat = BinTables(*[jnp.pad(x, pad[:x.ndim], mode="edge")
+                               for x in flat])
 
-    emitted = {e: [] for e in emit}
+        spec = _flatten_tenant_spec(spec_in, lead, k, k_pad)
+        mstate = jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (k_pad,) + x.shape),
+            pred_mod.init_state(cfg.predictor))
+        astate = jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (k_pad,) + x.shape),
+            pred_mod.init_state(cfg.avail_predictor))
+        backlog = jnp.zeros((k_pad, t), jnp.float32)
+        place = jnp.zeros((k_pad, t), jnp.float32)
+        if mesh is not None:
+            rules = shd.fleet_rules(mesh)
+            flat = shd.shard_fleet(flat, rules)
+            mstate = shd.shard_fleet(mstate, rules)
+            astate = shd.shard_fleet(astate, rules)
+            backlog = shd.shard_fleet(backlog, rules)
+            place = shd.shard_fleet(place, rules)
+            spec = shd.shard_fleet(spec, rules)
+
+        power_sum = np.zeros(k_pad, np.float64)
+        viol_sum = np.zeros(k_pad, np.float64)
+        backlog_sum = np.zeros(k_pad, np.float64)
+        offered_sum = np.zeros(k_pad, np.float64)
+        avail_sum = np.zeros(k_pad, np.float64)
+        t_viol_sum = np.zeros((k_pad, t), np.float64)
+        t_starve_sum = np.zeros((k_pad, t), np.float64)
+        t_served_sum = np.zeros((k_pad, t), np.float64)
+        t_offered_sum = np.zeros((k_pad, t), np.float64)
+
+        def chunked(rows, s0, n_valid):
+            """One [k_pad, C] device chunk of a lead + (S,) row set.
+
+            ``rows`` may be a stride-0 broadcast; slicing the step axis keeps
+            the view, so only k·C elements materialize per chunk — never K·S.
+            """
+            raw = np.ascontiguousarray(rows[..., s0:s0 + c]).reshape((k, -1))
+            if n_valid < c:
+                raw = np.pad(raw, ((0, 0), (0, c - n_valid)))
+            if k_pad != k:
+                raw = np.concatenate(
+                    [raw, np.broadcast_to(raw[:1], (k_pad - k, raw.shape[-1]))])
+            out = jnp.asarray(raw)
+            return shd.shard_fleet(out, rules) if mesh is not None else out
+
+        def chunked_plane(rows, s0, n_valid):
+            """One [k_pad, C, T] device chunk of the lead + (S, T) plane."""
+            raw = np.ascontiguousarray(
+                rows[..., s0:s0 + c, :]).reshape((k, -1, t))
+            if n_valid < c:
+                raw = np.pad(raw, ((0, 0), (0, c - n_valid), (0, 0)))
+            if k_pad != k:
+                raw = np.concatenate(
+                    [raw, np.broadcast_to(raw[:1],
+                                          (k_pad - k,) + raw.shape[1:])])
+            out = jnp.asarray(raw)
+            return shd.shard_fleet(out, rules) if mesh is not None else out
+
+        # Healthy fleets have a constant all-n_nodes schedule: build its
+        # device chunk once and reuse it, instead of re-materializing and
+        # re-transferring an identical [k_pad, C] array every chunk.
+        # (Padded/invalid steps never escape — the valid mask gates the
+        # carry and emits are cut to n_valid — so one chunk fits all.)
+        av_const = None
+        if avail is None:
+            av_const = jnp.full((k_pad, c), jnp.float32(cfg.n_nodes))
+            if mesh is not None:
+                av_const = shd.shard_fleet(av_const, rules)
+
+        emitted = {e: [] for e in emit}
+
     for s0 in range(0, s, c):
         n_valid = min(c, s - s0)
-        chunk = chunked_plane(traces, s0, n_valid)
-        av_chunk = (av_const if av_const is not None
-                    else chunked(avail_full, s0, n_valid))
-        valid = jnp.asarray(np.arange(c) < n_valid)
-        acc, ys = _fleet_stream_chunk_jit(flat, mstate, astate, backlog,
-                                          place, chunk, av_chunk, valid,
-                                          spec, sched_vals, cfg,
-                                          emit_internal)
-        mstate, astate = acc.mstate, acc.astate
-        backlog, place = acc.backlog, acc.place
-        power_sum += np.asarray(acc.power_sum, np.float64)
-        viol_sum += np.asarray(acc.viol_sum, np.float64)
-        backlog_sum += np.asarray(acc.backlog_sum, np.float64)
-        offered_sum += np.asarray(acc.offered_sum, np.float64)
-        avail_sum += np.asarray(acc.avail_sum, np.float64)
-        t_viol_sum += np.asarray(acc.t_viol_sum, np.float64)
-        t_starve_sum += np.asarray(acc.t_starve_sum, np.float64)
-        t_served_sum += np.asarray(acc.t_served_sum, np.float64)
-        t_offered_sum += np.asarray(acc.t_offered_sum, np.float64)
-        for e, y in zip(emit, ys):
-            emitted[e].append(np.asarray(y[:, :n_valid]))
+        with spans.span(spans.STREAM_STAGE):
+            chunk = chunked_plane(traces, s0, n_valid)
+            av_chunk = (av_const if av_const is not None
+                        else chunked(avail_full, s0, n_valid))
+            valid = jnp.asarray(np.arange(c) < n_valid)
+        with spans.span(spans.STREAM_DISPATCH):
+            acc, ys = _fleet_stream_chunk_jit(flat, mstate, astate, backlog,
+                                              place, chunk, av_chunk, valid,
+                                              spec, sched_vals, cfg,
+                                              emit_internal)
+        with spans.span(spans.STREAM_PULL):
+            mstate, astate = acc.mstate, acc.astate
+            backlog, place = acc.backlog, acc.place
+            power_sum += np.asarray(acc.power_sum, np.float64)
+            viol_sum += np.asarray(acc.viol_sum, np.float64)
+            backlog_sum += np.asarray(acc.backlog_sum, np.float64)
+            offered_sum += np.asarray(acc.offered_sum, np.float64)
+            avail_sum += np.asarray(acc.avail_sum, np.float64)
+            t_viol_sum += np.asarray(acc.t_viol_sum, np.float64)
+            t_starve_sum += np.asarray(acc.t_starve_sum, np.float64)
+            t_served_sum += np.asarray(acc.t_served_sum, np.float64)
+            t_offered_sum += np.asarray(acc.t_offered_sum, np.float64)
+            for e, y in zip(emit, ys):
+                emitted[e].append(np.asarray(y[:, :n_valid]))
 
-    def cut(x):
-        x = np.asarray(x)[:k]
-        return x.reshape(lead + x.shape[1:])
+    with spans.span(spans.STREAM_FINISH):
+        def cut(x):
+            x = np.asarray(x)[:k]
+            return x.reshape(lead + x.shape[1:])
 
-    backlog_np = np.asarray(backlog, np.float64)
-    served = offered_sum - backlog_np.sum(-1)
-    return FleetSummary(
-        mean_power_w=cut(power_sum / s),
-        qos_violation_rate=cut(viol_sum / s),
-        served_fraction=cut(served / np.maximum(offered_sum, 1e-9)),
-        mean_backlog=cut(backlog_sum / s),
-        final_backlog=cut(backlog_np.sum(-1)),
-        offered=cut(offered_sum),
-        mispredictions=cut(mstate.mispredictions),
-        n_steps=s,
-        final_predictor=jax.tree.map(cut, mstate),
-        emitted={e: cut(np.concatenate(v, axis=-1))
-                 for e, v in emitted.items()},
-        mean_avail_nodes=cut(avail_sum / s),
-        margin_misses=cut(mstate.margin_misses),
-        tenant_qos_violation_rate=cut(t_viol_sum / s),
-        tenant_starvation_rate=cut(t_starve_sum / s),
-        tenant_served_fraction=cut(t_served_sum
-                                   / np.maximum(t_offered_sum, 1e-9)),
-        tenant_final_backlog=cut(backlog_np))
+        backlog_np = np.asarray(backlog, np.float64)
+        served = offered_sum - backlog_np.sum(-1)
+        return FleetSummary(
+            mean_power_w=cut(power_sum / s),
+            qos_violation_rate=cut(viol_sum / s),
+            served_fraction=cut(served / np.maximum(offered_sum, 1e-9)),
+            mean_backlog=cut(backlog_sum / s),
+            final_backlog=cut(backlog_np.sum(-1)),
+            offered=cut(offered_sum),
+            mispredictions=cut(mstate.mispredictions),
+            n_steps=s,
+            final_predictor=jax.tree.map(cut, mstate),
+            emitted={e: cut(np.concatenate(v, axis=-1))
+                     for e, v in emitted.items()},
+            mean_avail_nodes=cut(avail_sum / s),
+            margin_misses=cut(mstate.margin_misses),
+            tenant_qos_violation_rate=cut(t_viol_sum / s),
+            tenant_starvation_rate=cut(t_starve_sum / s),
+            tenant_served_fraction=cut(t_served_sum
+                                       / np.maximum(t_offered_sum, 1e-9)),
+            tenant_final_backlog=cut(backlog_np))
 
 
 def fleet_node_nominal_watts(params: char.PlatformParams,

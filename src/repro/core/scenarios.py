@@ -45,6 +45,7 @@ import numpy as np
 from repro.core import characterization as char
 from repro.core import controller as ctl
 from repro.core import scheduler as sched_mod
+from repro.core import spans
 from repro.core import traces
 from repro.core import workload as wl
 from repro.runtime import elastic
@@ -628,93 +629,96 @@ def run_campaign(platforms: Sequence[ctl.PlatformSpec],
     exact-bin and beyond-margin miss rates — the gain-vs-misprediction
     sensitivity axes).
     """
-    missing = [p.name for p in platforms if p.params is None]
-    if missing:
-        raise ValueError(f"platforms lack PlatformParams: {missing}")
-    cfg = ctl.ControllerConfig(**cfg_kwargs)
-    if tenants is not None and not (tenants == "auto"
-                                    or (isinstance(tenants, int)
-                                        and tenants >= 1)):
-        raise ValueError(f"tenants must be None, 'auto', or an int >= 1, "
-                         f"got {tenants!r}")
-    spec = None
-    if tenants is None:
-        names, traces, avail = build_suite(scenario_names, n_steps=n_steps,
-                                           n_nodes=cfg.n_nodes, seed=seed)
-    else:
-        width = None if tenants == "auto" else int(tenants)
-        names, traces, avail, spec = build_tenant_suite(
-            scenario_names, n_steps=n_steps, n_nodes=cfg.n_nodes,
-            seed=seed, n_tenants=width)
-    params = char.stack_platform_params([p.params for p in platforms])
+    with spans.span(spans.ENTRY_INPUTS):
+        missing = [p.name for p in platforms if p.params is None]
+        if missing:
+            raise ValueError(f"platforms lack PlatformParams: {missing}")
+        cfg = ctl.ControllerConfig(**cfg_kwargs)
+        if tenants is not None and not (tenants == "auto"
+                                        or (isinstance(tenants, int)
+                                            and tenants >= 1)):
+            raise ValueError(f"tenants must be None, 'auto', or an int >= 1, "
+                             f"got {tenants!r}")
+        spec = None
+        if tenants is None:
+            names, traces, avail = build_suite(scenario_names, n_steps=n_steps,
+                                               n_nodes=cfg.n_nodes, seed=seed)
+        else:
+            width = None if tenants == "auto" else int(tenants)
+            names, traces, avail, spec = build_tenant_suite(
+                scenario_names, n_steps=n_steps, n_nodes=cfg.n_nodes,
+                seed=seed, n_tenants=width)
+        params = char.stack_platform_params([p.params for p in platforms])
     tables = ctl.fleet_bin_tables(params, cfg, techniques)     # [P, T, M]
-    n_scen = len(names)
-    # Scenario axis rides the tables' leading axes: broadcast [P, T, M] →
-    # [P, T, N, M] (free) and feed per-scenario traces + availability as
-    # [1, 1, N, S] (tenant planes as [1, 1, N, S, T], spec leaves as
-    # [1, 1, N, T]).
-    tab_n = ctl.BinTables(*[jnp.broadcast_to(
-        x[:, :, None], x.shape[:2] + (n_scen,) + x.shape[2:])
-        for x in tables])
-    if spec is not None:
-        spec = sched_mod.TenantSpec(*[x[None, None] for x in spec])
+    with spans.span(spans.ENTRY_INPUTS):
+        n_scen = len(names)
+        # Scenario axis rides the tables' leading axes: broadcast [P, T, M] →
+        # [P, T, N, M] (free) and feed per-scenario traces + availability as
+        # [1, 1, N, S] (tenant planes as [1, 1, N, S, T], spec leaves as
+        # [1, 1, N, T]).
+        tab_n = ctl.BinTables(*[jnp.broadcast_to(
+            x[:, :, None], x.shape[:2] + (n_scen,) + x.shape[2:])
+            for x in tables])
+        if spec is not None:
+            spec = sched_mod.TenantSpec(*[x[None, None] for x in spec])
     summary = ctl.simulate_fleet_stream(tab_n, traces[None, None], cfg,
                                         chunk_size=chunk_size, shard=shard,
                                         avail=avail[None, None],
                                         tenant_spec=spec)
-    node_nom_w = ctl.fleet_node_nominal_watts(params, cfg)     # [P]
-    nominal_cfg_w = node_nom_w * cfg.n_nodes                   # [P]
-    n_scored = max(n_steps - cfg.predictor.warmup_steps, 1)
+    with spans.span(spans.ENTRY_RESULTS):
+        node_nom_w = ctl.fleet_node_nominal_watts(params, cfg)     # [P]
+        nominal_cfg_w = node_nom_w * cfg.n_nodes                   # [P]
+        n_scored = max(n_steps - cfg.predictor.warmup_steps, 1)
 
-    table: Dict[str, Dict[str, Dict[str, Dict[str, float]]]] = {}
-    for i, plat in enumerate(platforms):
-        table[plat.name] = {}
-        for j, tech in enumerate(techniques):
-            table[plat.name][tech] = {}
-            for k, scen in enumerate(names):
-                mean_w = float(summary.mean_power_w[i, j, k])
-                mean_avail = float(summary.mean_avail_nodes[i, j, k])
-                cell = {
-                    "power_gain": float(node_nom_w[i]) * mean_avail / mean_w,
-                    "power_gain_vs_configured":
-                        float(nominal_cfg_w[i]) / mean_w,
-                    "mean_power_w": mean_w,
-                    "mean_avail_nodes": mean_avail,
-                    "qos_violation_rate":
-                        float(summary.qos_violation_rate[i, j, k]),
-                    "served_fraction":
-                        float(summary.served_fraction[i, j, k]),
-                    "mean_backlog": float(summary.mean_backlog[i, j, k]),
-                    "misprediction_rate":
-                        float(summary.mispredictions[i, j, k]) / n_scored,
-                    "margin_misprediction_rate":
-                        float(summary.margin_misses[i, j, k]) / n_scored,
-                }
-                if spec is not None:
-                    active = np.asarray(spec.active)[0, 0, k] > 0
-                    t_viol = summary.tenant_qos_violation_rate[i, j, k]
-                    cell["tenant_qos_violation_rate"] = [
-                        float(x) for x in t_viol]
-                    cell["tenant_starvation_rate"] = [
-                        float(x) for x in
-                        summary.tenant_starvation_rate[i, j, k]]
-                    cell["tenant_served_fraction"] = [
-                        float(x) for x in
-                        summary.tenant_served_fraction[i, j, k]]
-                    cell["worst_tenant_qos_violation"] = float(
-                        t_viol[active].max()) if active.any() else 0.0
-                table[plat.name][tech][scen] = cell
-    # Pareto reporting: per (platform, scenario), the non-dominated
-    # techniques over (power_gain ↑, qos_violation_rate ↓) — the
-    # power-vs-robustness trade failure campaigns track in benchmarks.
-    pareto: Dict[str, Dict[str, Tuple[str, ...]]] = {}
-    for plat in platforms:
-        pareto[plat.name] = {}
-        for scen in names:
-            pareto[plat.name][scen] = pareto_front(
-                {t: table[plat.name][t][scen] for t in techniques})
-    return {"scenarios": names, "techniques": tuple(techniques),
-            "n_steps": n_steps, "scheduler": cfg.scheduler.name,
-            "tenants": (None if spec is None
-                        else int(np.asarray(spec.active).shape[-1])),
-            "table": table, "pareto": pareto}
+        table: Dict[str, Dict[str, Dict[str, Dict[str, float]]]] = {}
+        for i, plat in enumerate(platforms):
+            table[plat.name] = {}
+            for j, tech in enumerate(techniques):
+                table[plat.name][tech] = {}
+                for k, scen in enumerate(names):
+                    mean_w = float(summary.mean_power_w[i, j, k])
+                    mean_avail = float(summary.mean_avail_nodes[i, j, k])
+                    cell = {
+                        "power_gain": float(node_nom_w[i]) * mean_avail / mean_w,
+                        "power_gain_vs_configured":
+                            float(nominal_cfg_w[i]) / mean_w,
+                        "mean_power_w": mean_w,
+                        "mean_avail_nodes": mean_avail,
+                        "qos_violation_rate":
+                            float(summary.qos_violation_rate[i, j, k]),
+                        "served_fraction":
+                            float(summary.served_fraction[i, j, k]),
+                        "mean_backlog": float(summary.mean_backlog[i, j, k]),
+                        "misprediction_rate":
+                            float(summary.mispredictions[i, j, k]) / n_scored,
+                        "margin_misprediction_rate":
+                            float(summary.margin_misses[i, j, k]) / n_scored,
+                    }
+                    if spec is not None:
+                        active = np.asarray(spec.active)[0, 0, k] > 0
+                        t_viol = summary.tenant_qos_violation_rate[i, j, k]
+                        cell["tenant_qos_violation_rate"] = [
+                            float(x) for x in t_viol]
+                        cell["tenant_starvation_rate"] = [
+                            float(x) for x in
+                            summary.tenant_starvation_rate[i, j, k]]
+                        cell["tenant_served_fraction"] = [
+                            float(x) for x in
+                            summary.tenant_served_fraction[i, j, k]]
+                        cell["worst_tenant_qos_violation"] = float(
+                            t_viol[active].max()) if active.any() else 0.0
+                    table[plat.name][tech][scen] = cell
+        # Pareto reporting: per (platform, scenario), the non-dominated
+        # techniques over (power_gain ↑, qos_violation_rate ↓) — the
+        # power-vs-robustness trade failure campaigns track in benchmarks.
+        pareto: Dict[str, Dict[str, Tuple[str, ...]]] = {}
+        for plat in platforms:
+            pareto[plat.name] = {}
+            for scen in names:
+                pareto[plat.name][scen] = pareto_front(
+                    {t: table[plat.name][t][scen] for t in techniques})
+        return {"scenarios": names, "techniques": tuple(techniques),
+                "n_steps": n_steps, "scheduler": cfg.scheduler.name,
+                "tenants": (None if spec is None
+                            else int(np.asarray(spec.active).shape[-1])),
+                "table": table, "pareto": pareto}

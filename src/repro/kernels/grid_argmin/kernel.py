@@ -135,6 +135,7 @@ def grid_argmin_fwd(params: char.PlatformParams, masks_flat: Array,
         out_specs=[out] * 4,
         out_shape=[shape] * 4,
         interpret=interpret,
+        name="grid_argmin",   # the trace's `%grid_argmin.N` operation
     )(*map(unit, (params.dl_weight, params.dl_vth, params.dl_alpha,
                   params.dl_v0, params.dl_rail,
                   params.delay_mode.reshape(n_p, 1).astype(jnp.int32),

@@ -11,6 +11,7 @@ the worker that is handed this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import pytest
@@ -84,6 +85,8 @@ def test_tables_program_picks_kernel_for_tpu(one_chip):
                                                  ctl.ControllerConfig()))
     text = ctl._fleet_dvfs_tables_jit.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    # The kernel's pinned name, which the trace reduction looks for.
+    assert re.search(r"%grid_argmin\.\d+ = .*custom-call\(", text)
 
 
 def test_stream_chunk_program_compiles(one_chip):
