@@ -204,6 +204,72 @@ def test_markov_warmup_disagreements_reach_threshold_counter():
     assert int(state.inner.consecutive_mispred) == 10
 
 
+def _dense_markov_chain(cfg, trace):
+    """The Markov family as a plain NumPy float32 chain over a dense
+    ``[M, M]`` count matrix, with the shell's warmup pinning and
+    clipping.  Sums run left to right, as the compiled reductions do.
+    The threshold-mode flush ``counts · decay + pending`` is rounded
+    once: XLA's CPU backend fuses that multiply-add into one operation,
+    at the parent's dense layout as at the flat one."""
+    m, f32 = cfg.n_bins, np.float32
+    counts = f32(0.01) * np.ones((m, m), f32) + np.eye(m, dtype=f32)
+    pending = np.zeros((m, m), f32)
+    cur, consecutive, preds = 0, 0, []
+    for t, w in enumerate(trace):
+        row = counts[cur]
+        total = f32(0)
+        for v in row:
+            total = f32(total + v)
+        probs = row / total
+        if cfg.policy == "argmax":
+            raw = int(np.argmax(probs))
+        elif cfg.policy == "expected":
+            mean = f32(0)
+            for j, p in enumerate(probs):
+                mean = f32(mean + p * f32(j))
+            raw = int(np.ceil(mean))
+        else:
+            cdf = np.cumsum(probs, dtype=f32)
+            raw = int(np.argmax(cdf >= f32(cfg.quantile)))
+        p = m - 1 if t < cfg.warmup_steps else min(max(raw, 0), m - 1)
+        preds.append(p)
+        actual = min(max(int(np.floor(f32(w) * f32(m))), 0), m - 1)
+        consecutive = consecutive + 1 if p != actual else 0
+        if cfg.update_mode == "always":
+            counts = counts * f32(cfg.count_decay)
+            counts[cur, actual] += f32(1)
+        else:
+            pending[cur, actual] += f32(1)
+            if consecutive >= cfg.mispred_threshold:
+                counts = (counts.astype(np.float64) * f32(cfg.count_decay)
+                          + pending).astype(f32)
+                pending[:] = 0
+                consecutive = 0
+        cur = actual
+    return np.asarray(preds), counts
+
+
+@pytest.mark.parametrize("decay", [1.0, 0.95])
+@pytest.mark.parametrize("update_mode", ["always", "threshold"])
+@pytest.mark.parametrize("policy", ["argmax", "quantile", "expected"])
+def test_markov_matches_dense_numpy_chain(policy, update_mode, decay):
+    """The flat ``[M·M]`` counts, read and updated through one-hot
+    masks, step exactly as a dense ``[M, M]`` chain: the same predicted
+    bin at every step and the same final counts, bit for bit."""
+    rng = np.random.default_rng(7)
+    walk = np.abs((0.5 + np.cumsum(rng.normal(0.0, 0.08, 400))) % 2.0)
+    trace = np.where(walk > 1.0, 2.0 - walk, walk).astype(np.float32)
+    cfg = pred.PredictorConfig(kind="markov", n_bins=12, warmup_steps=16,
+                               policy=policy, update_mode=update_mode,
+                               count_decay=decay)
+    ev = pred.evaluate_trace(cfg, trace)
+    preds, counts = _dense_markov_chain(cfg, trace)
+    assert len(set(preds)) > 6  # the chain visits most of its bins
+    np.testing.assert_array_equal(np.asarray(ev.predicted), preds)
+    np.testing.assert_array_equal(
+        np.asarray(ev.final_state.inner.counts).reshape(12, 12), counts)
+
+
 def test_quantile_policy_is_more_conservative():
     """Beyond-paper: the quantile policy never under-predicts more often
     than argmax on a noisy trace."""

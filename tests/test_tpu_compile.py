@@ -14,6 +14,7 @@ import os
 import re
 
 import jax
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -98,3 +99,63 @@ def test_stream_chunk_program_compiles(one_chip):
         *args, ctl._runtime_cfg(cfg), ()).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def _while_body_instructions(text):
+    """``(opcode, dims, operand dims)`` of every array instruction that
+    the compiled program's while loops run, fused computations included.
+    ``operand dims`` is the shape of the first operand, where the same
+    computation defines it."""
+    comps, lines = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            lines = comps.setdefault(head.group(1), [])
+        elif lines is not None and line.startswith("  "):
+            lines.append(line)
+    todo, seen = re.findall(r"body=%([\w.\-]+)", text), set()
+    while todo:
+        name = todo.pop()
+        if name in comps and name not in seen:
+            seen.add(name)
+            todo += [c for line in comps[name]
+                     for c in re.findall(r"calls=%([\w.\-]+)", line)]
+    inst = re.compile(
+        r"%([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(%?([\w.\-]*)")
+    out = []
+    for name in seen:
+        dims = {}
+        for line in comps[name]:
+            m = inst.search(line)
+            if m:
+                dims[m.group(1)] = tuple(
+                    int(d) for d in m.group(2).split(",") if d)
+                out.append((m.group(3), dims[m.group(1)],
+                            dims.get(m.group(4))))
+    return out
+
+
+@pytest.mark.parametrize("k, chunk", [(CAMPAIGN_K, 1024), (5000, 512)])
+def test_chunk_program_moves_no_markov_state(one_chip, k, chunk):
+    """Inside the chunk program's loop the Markov counts are read and
+    updated in place: no scatter, no gather from an operand with the
+    counts' M·M elements per cell, and no copy of the counts.  A
+    row gather and an edge scatter want different layouts of the
+    carried counts, and the compiler then relays the whole state out
+    every step."""
+    cfg = ctl.ControllerConfig()
+    args = _on(one_chip, aot.stream_program_args(cfg, k, chunk))
+    text = ctl._fleet_stream_chunk_jit.lower(
+        *args, ctl._runtime_cfg(cfg), ()).compile().as_text()
+    body = _while_body_instructions(text)
+    state = k * cfg.n_bins ** 2
+
+    def elements(dims):
+        return None if dims is None else int(np.prod(dims))
+
+    assert any(op == "fusion" for op, _, _ in body)
+    assert not [i for i in body if i[0] == "scatter"]
+    assert not [i for i in body
+                if i[0] == "gather" and elements(i[2]) == state]
+    assert not [i for i in body if i[0] in ("copy", "copy-start", "transpose")
+                and elements(i[1]) == state]
