@@ -29,9 +29,9 @@ def readings(workload: str, seed: int, sizes: dict = None) -> dict:
     import run_cell
     from harness import check
     from harness import traffic as traffic_gen
-    from reference import model
     spec = run_cell.resolve(workload)
     cfg, mix = spec["config"], spec["mix"]
+    model = run_cell.reference(cfg)
     for k, v in (sizes or {}).items():
         cfg[k] = {**cfg[k], **v} if isinstance(v, dict) else v
     scen = traffic_gen.generate(mix, cfg["n_steps"], seed)

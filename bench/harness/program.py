@@ -53,10 +53,17 @@ def register(scen: Sequence) -> List[str]:
 
 
 def controller_kwargs(cfg: dict, mix: dict) -> dict:
-    """``ControllerConfig`` keyword arguments stated by a configuration."""
+    """``ControllerConfig`` keyword arguments stated by a configuration
+    (``headroom_frac`` only where it states one; ``avail_predictor`` a
+    name, or a dict of ``PredictorConfig`` fields)."""
     from repro.core import predictors as pred_mod
     c = cfg["controller"]
     pc = c["predictor"]
+    extra = ({"headroom_frac": c["headroom_frac"]} if "headroom_frac" in c
+             else {})
+    ap = c["avail_predictor"]
+    if isinstance(ap, dict):
+        ap = pred_mod.PredictorConfig(**ap)
     return dict(
         n_nodes=c["n_nodes"], n_bins=c["n_bins"], margin=c["margin"],
         tau=c["tau"], f_floor=c["f_floor"],
@@ -65,8 +72,8 @@ def controller_kwargs(cfg: dict, mix: dict) -> dict:
             kind=pc["kind"], policy=pc["policy"],
             update_mode=pc["update_mode"], count_decay=pc["count_decay"],
             warmup_steps=pc["warmup_steps"]),
-        avail_predictor=c["avail_predictor"],
-        scheduler=mix.get("scheduler", "none"))
+        avail_predictor=ap,
+        scheduler=mix.get("scheduler", "none"), **extra)
 
 
 @dataclasses.dataclass

@@ -14,7 +14,10 @@ would return before the program's own post-processing:
 * ``trace``  — the raw workload builder output ``[S]`` (clipped later);
 * ``nodes``  — the alive-fraction schedule ``[S]`` or ``None``;
 * ``tenants`` — ``(parts [T, S] float64, spec)`` or ``None``, where
-  ``spec`` holds per-tenant ``priority``/``latency_target``/``share``.
+  ``spec`` holds per-tenant ``priority``/``latency_target``/``share``.  A
+  ``mix`` scenario's tenants are its components; a component may state
+  its tenant class, ``"class": {"priority": p, "latency_target": l}``,
+  and one that states none is priority 1, latency target 0.
 
 Every scenario draws from ``default_rng([seed, crc32(salt)])`` exactly as
 the program salts its scenarios, so at a fixed seed the copies reproduce
@@ -299,8 +302,10 @@ class Generator:
             parts = np.stack([wi * np.asarray(
                 self._component(c, n, _child(rng)), np.float64)
                 for wi, c in zip(w, e["components"])])
-            t = len(w)
-            return parts, _spec([1.0] * t, [0.0] * t, w)
+            cls = [c.get("class", {}) for c in e["components"]]
+            return parts, _spec([c.get("priority", 1.0) for c in cls],
+                                [c.get("latency_target", 0.0) for c in cls],
+                                w)
         parts, weights = _PARTS[e["shape"]](n, rng)
         parts = np.stack([np.asarray(p, np.float64) for p in parts])
         cls = e["tenants"]

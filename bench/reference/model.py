@@ -4,8 +4,9 @@ Everything is derived from a configuration file (``bench/configs/``) and
 the generated traffic: the accelerator characterization (paper Table I and
 the delay/power library), the section V operating tables (a brute-force
 search of the voltage grid), the control loop (Markov predictor, bin
-selection, availability clamp, tenant scheduler) and the host-side
-reductions.  It imports nothing of the program.
+selection, the ``headroom`` bump and its availability forecaster, the
+availability clamp, tenant scheduler) and the host-side reductions.  It
+imports nothing of the program.
 
 Arithmetic runs in a chosen type ``dt`` (the configuration states float32
 on the device) and the long sums in ``acc`` (float64 on the host, as the
@@ -180,7 +181,9 @@ def best_points(plat: Platform, mask: np.ndarray, levels, vc, vb,
 
 def operating_tables(cfg: dict, p: Prec = F32) -> Dict[str, np.ndarray]:
     """Per-bin tables ``{field: [P, T, M]}`` for the configuration's
-    platforms and techniques."""
+    platforms and techniques, and each technique's failure reserve
+    ``reserve [P, T]``: ``headroom`` runs ``hybrid``'s rows and reserves
+    the configuration's ``headroom_frac``; every other technique 0."""
     charz, ctl = cfg["characterization"], cfg["controller"]
     rails = charz["rails"]
     m, n = ctl["n_bins"], ctl["n_nodes"]
@@ -222,7 +225,7 @@ def operating_tables(cfg: dict, p: Prec = F32) -> Dict[str, np.ndarray]:
                     v_core=pts["v_core"], v_bram=pts["v_bram"], f_rel=levels,
                     n_active=np.full(m, n, p.dt), node_power=node_w + pll_w,
                     gated_power=np.zeros(m, p.dt))
-            elif tech == "hybrid":
+            elif tech in ("hybrid", "headroom"):
                 g_pts = [best_points(plat, full, f_node[g], vc, vb,
                                      ctl["slack_eps"], p)
                          for g in range(n)]
@@ -263,6 +266,10 @@ def operating_tables(cfg: dict, p: Prec = F32) -> Dict[str, np.ndarray]:
                                   for t in cfg["techniques"]])
                         for r in per_plat]) for f in TABLE_FIELDS}
     out["node_nominal_w"] = np.asarray(node_nominal)   # one node + PLLs
+    frac = [ctl["headroom_frac"] if t == "headroom" else 0.0
+            for t in cfg["techniques"]]
+    out["reserve"] = np.tile(np.asarray(frac, np.float64),
+                             (len(cfg["platforms"]), 1))
     return out
 
 
@@ -342,18 +349,81 @@ def campaign_inputs(cfg: dict, mix: dict, scen: Sequence):
 
 
 # ---------------------------------------------------------------------------
+# The headroom bump and its availability forecaster
+# ---------------------------------------------------------------------------
+
+
+def avail_warmup(ctl: dict) -> int:
+    """Warm-up of the availability forecaster as the configuration states
+    it (``"avail_predictor": {"kind": "persistence", "warmup_steps": w}``):
+    a persistence forecaster over ``n_nodes`` bins, the only family the
+    reference has."""
+    ap = ctl["avail_predictor"]
+    if not (isinstance(ap, dict) and ap.get("kind") == "persistence"
+            and "warmup_steps" in ap):
+        raise ValueError(f"the reference runs the headroom bump only on a "
+                         f"persistence forecaster with a stated warm-up, "
+                         f"not {ap!r}")
+    return int(ap["warmup_steps"])
+
+
+def avail_forecast(last: np.ndarray, step: int, warm: int, n: int,
+                   p: Prec) -> np.ndarray:
+    """Forecast usable nodes ``â = (b + 1) / n · n`` clipped to ``[1, n]``,
+    ``b`` the last observed bin; warm-up pins the top bin, so a cold
+    forecaster assumes a healthy fleet."""
+    b = np.full_like(last, n - 1) if step < warm else last
+    return np.clip((p.a(b) + p.c(1.0)) / p.c(n) * p.c(n), p.c(1.0), p.c(n))
+
+
+def avail_bin(a_t: np.ndarray, n: int, p: Prec) -> np.ndarray:
+    """The bin the forecaster observes for ``a`` usable nodes:
+    ``(a − 0.5) / n`` in ``n`` bins, so ``a`` nodes are bin ``a − 1``."""
+    frac = (a_t - p.c(0.5)) / p.c(n)
+    return np.clip(np.floor(frac * p.c(n)), 0, n - 1).astype(np.int64)
+
+
+def headroom_bump(tab: Dict[str, np.ndarray], reserve: np.ndarray,
+                  a_hat: np.ndarray, selected: np.ndarray,
+                  b_agg: np.ndarray, n: int, m: int, p: Prec) -> np.ndarray:
+    """Raise each reserving cell's bin to the lowest one whose delivery
+    at the forecast availability covers the selected bin's level plus the
+    carried backlog; delivery is planned for at most ``spare =
+    ceil(frac · n − 1e-9)`` lost nodes.  Never below ``selected``; cells
+    that reserve nothing keep ``selected``."""
+    spare = p.a(np.ceil(reserve * n - 1e-9))
+    a_res = np.clip(a_hat, p.c(n) - spare, p.c(n))
+    needed = np.minimum((p.a(selected) + p.c(1.0)) / p.c(m) + b_agg,
+                        np.max(tab["capacity"], axis=1))
+    n_tab = tab["n_active"]
+    delivered = tab["capacity"] * (np.minimum(n_tab, a_res[:, None])
+                                   / np.maximum(n_tab, p.c(1.0)))
+    covers = delivered >= (needed - p.c(EPS))[:, None]
+    lowest = np.where(covers.any(axis=1), np.argmax(covers, axis=1), m - 1)
+    return np.where(reserve > 0, np.maximum(selected, lowest), selected)
+
+
+# ---------------------------------------------------------------------------
 # The control loop over K independent cells
 # ---------------------------------------------------------------------------
 
 
 def control_loop(tab: Dict[str, np.ndarray], w: np.ndarray, avail: np.ndarray,
                  spec: Dict[str, np.ndarray], ctl: dict, scheduler: str,
-                 chunk: int, p: Prec = F32) -> Dict[str, np.ndarray]:
+                 chunk: int, p: Prec = F32,
+                 reserve: Optional[np.ndarray] = None
+                 ) -> Dict[str, np.ndarray]:
     """Run every cell's loop; ``tab`` fields are ``[K, M]``, ``w`` is
-    ``[K, S, T]`` and ``avail`` ``[K, S]``.  Sums restart each chunk of
-    ``chunk`` steps and are added up in ``p.acc``."""
+    ``[K, S, T]`` and ``avail`` ``[K, S]``; ``reserve [K]`` is each cell's
+    ``headroom`` reserve (none: no cell reserves).  Sums restart each
+    chunk of ``chunk`` steps and are added up in ``p.acc``."""
     k, s, t = w.shape
     m = ctl["n_bins"]
+    n = ctl["n_nodes"]
+    bumps = reserve is not None and bool(np.any(reserve > 0))
+    if bumps:
+        a_warm = avail_warmup(ctl)
+        a_last = np.full(k, n - 1, np.int64)
     warm = ctl["predictor"]["warmup_steps"]
     margin_bins = int(np.floor(ctl["margin"] * m + 1e-9))
     on = scheduler != "none"
@@ -419,6 +489,11 @@ def control_loop(tab: Dict[str, np.ndarray], w: np.ndarray, avail: np.ndarray,
                                                              shaped)
                 bump = (b_agg >= gap) & (eff_best > shaped)
                 selected = np.where(bump, eff_best, shaped)
+            if bumps:
+                a_hat = avail_forecast(a_last, step, a_warm, n, p)
+                selected = headroom_bump(tab, reserve, a_hat, selected,
+                                         b_agg, n, m, p)
+                a_last = avail_bin(a_t, n, p)
             n_tab = take(tab["n_active"], selected)
             n_act = np.minimum(n_tab, a_t)
             cap = take(tab["capacity"], selected) * (n_act
@@ -518,7 +593,8 @@ def campaign(cfg: dict, mix: dict, scen: Sequence, p: Prec = F32) -> dict:
     flat = {f: np.repeat(tabs[f].reshape(-1, tabs[f].shape[-1]), n_scen,
                          axis=0) for f in TABLE_FIELDS}
     out = control_loop(flat, w, av, spec, cfg["controller"],
-                       mix.get("scheduler", "none"), cfg["chunk_size"], p)
+                       mix.get("scheduler", "none"), cfg["chunk_size"], p,
+                       reserve=np.repeat(tabs["reserve"].reshape(-1), n_scen))
     nom = np.repeat(tabs["node_nominal_w"], len(cfg["techniques"]) * n_scen)
     out["power_gain"] = nom * out["mean_avail_nodes"] / out["mean_power_w"]
     out["tables"] = {f: tabs[f] for f in TABLE_FIELDS}

@@ -7,7 +7,11 @@ Everything is found by name from ``BENCHMARK.json`` at the checkout root:
 the cell names a configuration (``bench/configs/<config>.json``) and a
 traffic mix (``bench/traffic/<mix>.json``); its limits for ``correct`` are
 ``bench/limits/<cell>.json`` and each per-layer metric is read by
-``bench/metrics/<metric>.py``.
+``bench/metrics/<metric>.py``.  A mix may state the ``techniques`` its cell
+sweeps and ``controller`` settings over the configuration's; a
+configuration may name its plain reference (``"reference": "<name>"``:
+``bench/reference/<name>.py`` run over ``model.py``, whose functions it
+replaces; default ``model`` alone).
 
 A run: set-up (device check, compile cache, traffic from ``--seed``,
 ahead-of-time compile of the cell's two programs, one warm-up call), then
@@ -32,6 +36,7 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+import types  # noqa: E402
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
@@ -47,8 +52,21 @@ def load_json(path: str) -> dict:
         return json.load(f)
 
 
+def configured(cfg: dict, mix: dict) -> dict:
+    """The configuration as the mix runs it: the mix's ``techniques`` in
+    place of the configuration's, its ``controller`` settings over the
+    configuration's.  A mix that states neither leaves it as it is."""
+    cfg = dict(cfg)
+    if "techniques" in mix:
+        cfg["techniques"] = list(mix["techniques"])
+    if "controller" in mix:
+        cfg["controller"] = {**cfg["controller"], **mix["controller"]}
+    return cfg
+
+
 def resolve(workload: str) -> dict:
-    """The cell, its configuration, traffic, limits and metrics by name."""
+    """The cell, its configuration (as its mix runs it), traffic, limits
+    and metrics by name."""
     path = os.path.join(ROOT, "BENCHMARK.json")
     if not os.path.exists(path):
         raise CellError(f"no BENCHMARK.json at {ROOT}")
@@ -58,12 +76,12 @@ def resolve(workload: str) -> dict:
         raise CellError(f"unknown workload {workload!r}; known: "
                         f"{sorted(cells)}")
     cell = cells[workload]
+    mix = load_json(os.path.join(BENCH, "traffic", cell["traffic"] + ".json"))
     return {
         "cell": cell,
-        "config": load_json(os.path.join(BENCH, "configs",
-                                         cell["config"] + ".json")),
-        "mix": load_json(os.path.join(BENCH, "traffic",
-                                      cell["traffic"] + ".json")),
+        "config": configured(load_json(os.path.join(
+            BENCH, "configs", cell["config"] + ".json")), mix),
+        "mix": mix,
         "limits": load_json(os.path.join(BENCH, "limits",
                                          workload + ".json"))["limits"],
         "per_layer": man["per_layer"],
@@ -89,6 +107,23 @@ def metric_reader(name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def reference(cfg: dict):
+    """The plain reference the configuration names (``"reference":
+    "<name>"``, default ``model``): ``bench/reference/model.py`` with
+    ``bench/reference/<name>.py`` run over it in one namespace, so each
+    function the named file defines replaces model's own wherever model's
+    loop calls it."""
+    name = cfg.get("reference", "model")
+    mod = types.ModuleType("bench_reference_" + name)
+    mod.__file__ = os.path.join(BENCH, "reference", name + ".py")
+    sys.modules[mod.__name__] = mod  # dataclasses look their module up
+    for part in dict.fromkeys(("model", name)):
+        path = os.path.join(BENCH, "reference", part + ".py")
+        with open(path) as f:
+            exec(compile(f.read(), path, "exec"), mod.__dict__)
+    return mod
 
 
 class CompileWatch:
@@ -129,7 +164,6 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     sys.path.insert(0, os.path.join(ROOT, "src"))
     from harness import check, names, program, tracing
     from harness import traffic as traffic_gen
-    from reference import model as ref_model
     from repro.core import aot
     from repro.core import controller as ctl
 
@@ -219,6 +253,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     r0 = time.perf_counter()
     per_call = []
     if outs:
+        ref_model = reference(cfg)
         names_ = [program.PREFIX + sc.name for sc in scen]
         if cfg["entry"] == "campaign":
             ref = ref_model.campaign(cfg, mix, scen)

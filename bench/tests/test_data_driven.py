@@ -81,3 +81,80 @@ def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
 
     for p, data in before.items():
         assert p.read_bytes() == data, f"{p} was edited"
+
+
+def test_new_reference_module_and_mix_techniques_are_found_by_name(tmp_path):
+    """A configuration names its own plain reference (a new module that
+    defines only the functions its mechanism changes, run over
+    ``model.py``) and a mix states the techniques and controller settings
+    its cell sweeps: both are found by name, model's loop calls the new
+    module's function, and the harness, the control and the bench tests
+    all see the same configuration."""
+    root = _copy(tmp_path)
+    b = root / "bench"
+    before = {p: p.read_bytes() for p in b.rglob("*") if p.is_file()}
+
+    (b / "reference" / "tiered.py").write_text(
+        "def headroom_bump(tab, reserve, a_hat, selected, b_agg, n, m, p):\n"
+        "    return selected\n")
+    cfg = json.loads((b / "configs" / "fpga5-paper.json").read_text())
+    cfg.update(name="fpga5-tiered", reference="tiered", n_steps=512,
+               chunk_size=128)
+    (b / "configs" / "fpga5-tiered.json").write_text(json.dumps(cfg))
+    mix = json.loads((b / "traffic" / "aggregate.json").read_text())
+    mix["scenarios"] = {k: mix["scenarios"][k] for k in ("burse",
+                                                         "node_failure")}
+    mix["techniques"] = ["hybrid", "headroom"]
+    mix["controller"] = {"headroom_frac": 0.25, "avail_predictor": {
+        "kind": "persistence", "warmup_steps": 32}}
+    (b / "traffic" / "headroom-two.json").write_text(json.dumps(mix))
+    (b / "limits" / "fpga5-tiered.headroom-two.json").write_text(
+        json.dumps({"limits": {"power": 1e-3}}))
+    man = json.loads((root / "BENCHMARK.json").read_text())
+    man["configs"].append({"name": "fpga5-tiered", "source": "test",
+                           "file": "bench/configs/fpga5-tiered.json",
+                           "reduced": [], "why": "test"})
+    man["workloads"].append({"name": "fpga5-tiered.headroom-two",
+                             "config": "fpga5-tiered",
+                             "traffic": "headroom-two", "chips": 1,
+                             "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(man))
+
+    run_cell = _module(b / "run_cell.py", "run_cell_copy2")
+    spec = run_cell.resolve("fpga5-tiered.headroom-two")
+    cfg = spec["config"]
+    assert cfg["techniques"] == ["hybrid", "headroom"]
+    assert cfg["controller"]["headroom_frac"] == 0.25
+    assert cfg["controller"]["n_nodes"] == 8            # the rest kept
+    ref = run_cell.reference(cfg)
+    assert ref.__file__ == str(b / "reference" / "tiered.py")
+    plain_ref = run_cell.reference({**cfg, "reference": "model"})
+    assert plain_ref.__file__ == str(b / "reference" / "model.py")
+
+    traffic = _module(b / "harness" / "traffic.py", "traffic_copy2")
+    scen = traffic.generate(spec["mix"], cfg["n_steps"], 5)
+    out = ref.campaign(cfg, spec["mix"], scen)
+    base = plain_ref.campaign(cfg, spec["mix"], scen)
+    assert out["mean_power_w"].shape == (5 * 2 * 2,)
+    tabs = ref.operating_tables(cfg)
+    assert tabs["reserve"].tolist() == [[0.0, 0.25]] * 5
+    assert np.array_equal(tabs["power"][:, 0], tabs["power"][:, 1])
+    # [P, T, S]: with the bump replaced, headroom runs as hybrid does.
+    power = out["mean_power_w"].reshape(5, 2, 2)
+    assert np.array_equal(power[:, 1], power[:, 0])
+    assert np.array_equal(out["mean_power_w"].reshape(5, 2, 2)[:, 0],
+                          base["mean_power_w"].reshape(5, 2, 2)[:, 0])
+    assert not np.array_equal(base["mean_power_w"].reshape(5, 2, 2)[:, 1],
+                              power[:, 1])
+
+    program = _module(b / "harness" / "program.py", "program_copy2")
+    kw = program.controller_kwargs(cfg, spec["mix"])
+    assert kw["headroom_frac"] == 0.25
+    assert kw["avail_predictor"].warmup_steps == 32
+    plain = run_cell.resolve("fpga5-paper.aggregate")["config"]
+    assert "headroom_frac" not in program.controller_kwargs(plain, {})
+    assert run_cell.reference(plain).__file__ == str(b / "reference"
+                                                     / "model.py")
+
+    for p, data in before.items():
+        assert p.read_bytes() == data, f"{p} was edited"

@@ -4,14 +4,18 @@ Each test skips the look for a chip, drives the rest of a run at a small
 size on the CPU with one fault planted in the program, and checks
 ``correct``: a step that returns its state unchanged; half of the cells
 left out (their results taken from the other half); an answer altered
-where it is produced (one cell's power sum of one chunk 10 % high).  An
-unbroken run passes.  The four-chip cell runs on four virtual CPU devices
-in a process of its own, with one more fault: the exchange between chips
-left out (the second chip's cells come back with the first chip's
-results, as if its shard were never gathered).
+where it is produced (one cell's power sum of one chunk 10 % high); in
+the cell whose mechanism is the ``headroom`` bump, the bump left out (it
+returns the selected bin) and its availability forecast one node high
+(as if it observed one usable node more).  An unbroken run passes.  The
+four-chip cell runs on four virtual CPU devices in a process of its own,
+with one more fault: the exchange between chips left out (the second
+chip's cells come back with the first chip's results, as if its shard
+were never gathered).
 """
 
 import contextlib
+import functools
 import json
 import os
 import subprocess
@@ -34,15 +38,19 @@ def _sizes(workload):
 
 @contextlib.contextmanager
 def _patched(attr, make):
+    """Plant ``make(real)`` at ``attr``, a name in the controller module
+    or a dotted path from it."""
     from repro.core import controller as ctl
     jit = ctl._fleet_stream_chunk_jit
-    real = getattr(ctl, attr)
-    setattr(ctl, attr, make(real))
+    *path, name = attr.split(".")
+    owner = functools.reduce(getattr, path, ctl)
+    real = getattr(owner, name)
+    setattr(owner, name, make(real))
     jit.clear_cache()          # the chunk program retraces with the fault
     try:
         yield
     finally:
-        setattr(ctl, attr, real)
+        setattr(owner, name, real)
         jit.clear_cache()
 
 
@@ -102,14 +110,30 @@ def answer_altered(real):
     return chunk
 
 
+def bump_left_out(real):
+    def bump(tables, cfg, astate, selected, backlog_agg):
+        return selected
+    return bump
+
+
+def forecast_node_high(real):
+    def forecast(pcfg, state):
+        return real(pcfg, state) + 1.0 / pcfg.n_bins
+    return forecast
+
+
 FAULTS = {
     "state_unchanged": ("_control_step", state_unchanged),
+    "bump_left_out": ("_headroom_bump", bump_left_out),
+    "forecast_node_high": ("pred_mod.forecast_fraction", forecast_node_high),
     "half_left_out": ("simulate_fleet_stream", half_left_out),
     "answer_altered": ("_fleet_stream_chunk_jit", answer_altered),
     "exchange_left_out": ("simulate_fleet_stream", exchange_left_out),
 }
 ONE_CHIP = ("state_unchanged", "half_left_out", "answer_altered")
 CASES = ([("fpga5-paper.aggregate", f) for f in ONE_CHIP]
+         + [("fpga5-paper.failures-headroom", f)
+            for f in ONE_CHIP + ("bump_left_out", "forecast_node_high")]
          + [("compose-fpga5.quickstart", f) for f in ONE_CHIP])
 FOUR_CHIP = "compose-fpga5.quickstart-4chip"
 
@@ -130,6 +154,7 @@ def test_fault_is_not_correct(workload, fault):
 
 
 @pytest.mark.parametrize("workload", ["fpga5-paper.aggregate",
+                                      "fpga5-paper.failures-headroom",
                                       "compose-fpga5.quickstart"])
 def test_unbroken_run_is_correct(workload):
     res = _run(workload)

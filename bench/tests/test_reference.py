@@ -9,6 +9,7 @@ import os
 import numpy as np
 import pytest
 
+import run_cell
 from harness import check, program
 from harness import traffic as tg
 from reference import model
@@ -57,10 +58,12 @@ def _stated_provision_bin(spec, predicted_bin, backlog_t, n_bins):
     return jnp.clip(b, 0, n_bins - 1)
 
 
-def _campaign_gaps(mix_name, n_steps=400, chunk=128):
-    cfg = _small(_load("configs", "fpga5-paper"), n_steps=n_steps,
-                 chunk_size=chunk)
+def _campaign(mix_name, n_steps, chunk):
+    """The program's statistics and tables, and the reference's, for a
+    campaign of ``fpga5-paper`` as ``mix_name`` runs it."""
     mix = _load("traffic", mix_name)
+    cfg = run_cell.configured(_small(_load("configs", "fpga5-paper"),
+                                     n_steps=n_steps, chunk_size=chunk), mix)
     scen = tg.generate(mix, cfg["n_steps"], SEED)
     entry = program.entry(cfg, mix, scen)
     try:
@@ -68,10 +71,14 @@ def _campaign_gaps(mix_name, n_steps=400, chunk=128):
         tabs = check.host_tables(entry.tables[0])
     finally:
         entry.restore()
-    ref = model.campaign(cfg, mix, scen)
     names = [program.PREFIX + s.name for s in scen]
-    return check.gaps("campaign", check.campaign_stats(
-        cfg, out, names, mix["tenants"] is not None), tabs, ref)
+    stats = check.campaign_stats(cfg, out, names, mix["tenants"] is not None)
+    return cfg, scen, stats, tabs, model.campaign(cfg, mix, scen)
+
+
+def _campaign_gaps(mix_name, n_steps=400, chunk=128):
+    _, _, stats, tabs, ref = _campaign(mix_name, n_steps, chunk)
+    return check.gaps("campaign", stats, tabs, ref)
 
 
 @pytest.fixture
@@ -87,20 +94,21 @@ def stated_division():
     ctl._fleet_stream_chunk_jit.clear_cache()
 
 
-def _agree(g, tenants):
+def _agree(g):
     assert g["tables_power"] < 1e-5 and g["tables_volt"] == 0.0
     assert g["power"] < 1e-5
     for k in ("qos", "served", "backlog", "mispred"):
         assert g[k] < 1e-6, (k, g[k])
-    if tenants:
+    if "tenant_qos" in g:
         assert g["tenant_qos"] < 1e-6 and g["tenant_served"] < 1e-6
 
 
-@pytest.mark.parametrize("mix_name", ["aggregate", "tenants-priority"])
+@pytest.mark.parametrize("mix_name", ["aggregate", "tenants-priority",
+                                      "failures-headroom"])
 def test_campaign_agrees(mix_name, stated_division):
     """On the tenant plane only with the level computed as the program
     states it (see ``test_compiled_level_is_one_bin_low``)."""
-    _agree(_campaign_gaps(mix_name), mix_name != "aggregate")
+    _agree(_campaign_gaps(mix_name))
 
 
 def test_compiled_level_is_one_bin_low():
@@ -113,6 +121,25 @@ def test_compiled_level_is_one_bin_low():
     stated: the cell can then come back."""
     g = _campaign_gaps("tenants-priority", n_steps=1024, chunk=1024)
     assert g["power"] > 1e-2 and g["qos"] > 1e-2, g
+
+
+def test_headroom_buys_qos_with_power():
+    """The failures-headroom cell's mechanism acts: in the program's
+    results, and the reference's, a ``headroom`` cell draws more power
+    and violates QoS less often than the ``hybrid`` cell of its platform
+    and failure scenario, in every such pair (their tables are the same)."""
+    cfg, scen, stats, _, ref = _campaign("failures-headroom", 512, 128)
+    keys = [(p, t, s.name) for p in cfg["platforms"]
+            for t in cfg["techniques"] for s in scen]
+    for res in (stats, ref):
+        for p in cfg["platforms"]:
+            for s in scen:
+                hyb = keys.index((p, "hybrid", s.name))
+                head = keys.index((p, "headroom", s.name))
+                assert (res["mean_power_w"][head]
+                        > res["mean_power_w"][hyb]), (p, s.name)
+                assert (res["qos_violation_rate"][head]
+                        < res["qos_violation_rate"][hyb]), (p, s.name)
 
 
 def test_composition_agrees():

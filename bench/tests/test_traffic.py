@@ -61,3 +61,30 @@ def test_same_seed_same_traffic_and_sizes_fixed():
     for x, y, z in zip(a, b, c):
         assert np.array_equal(x.trace, y.trace)
         assert x.trace.shape == z.trace.shape
+
+
+def _two_tenant_mix(classes):
+    comps = [{"sample": "azure_vm_cpu.csv"}, {"scenario": "burse"}]
+    for comp, cls in zip(comps, classes):
+        if cls is not None:
+            comp["class"] = cls
+    return {"scenarios": {
+        "burse": _mix("aggregate")["scenarios"]["burse"],
+        "tiers": {"shape": "mix", "components": comps,
+                  "weights": [0.7, 0.3]}}, "tenants": "auto"}
+
+
+def test_mix_component_states_its_tenant_class():
+    """A ``mix`` component's ``class`` sets its tenant's priority and
+    latency target; a component without one keeps priority 1, latency
+    target 0; the arrays do not depend on the classes."""
+    plain = tg.generate(_two_tenant_mix([None, None]), 300, 11)[1]
+    tiered = tg.generate(_two_tenant_mix(
+        [{"priority": 2.0, "latency_target": 4.0}, None]), 300, 11)[1]
+    assert plain.tenants[1]["priority"] == [1.0, 1.0]
+    assert plain.tenants[1]["latency_target"] == [0.0, 0.0]
+    assert tiered.tenants[1]["priority"] == [2.0, 1.0]
+    assert tiered.tenants[1]["latency_target"] == [4.0, 0.0]
+    assert tiered.tenants[1]["share"] == plain.tenants[1]["share"]
+    assert np.array_equal(tiered.tenants[0], plain.tenants[0])
+    assert np.array_equal(tiered.trace, plain.trace)
