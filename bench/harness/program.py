@@ -54,8 +54,9 @@ def register(scen: Sequence) -> List[str]:
 
 def controller_kwargs(cfg: dict, mix: dict) -> dict:
     """``ControllerConfig`` keyword arguments stated by a configuration
-    (``headroom_frac`` only where it states one; ``avail_predictor`` a
-    name, or a dict of ``PredictorConfig`` fields)."""
+    (``headroom_frac`` and the predictor's ``ewma_alpha`` only where it
+    states them; ``avail_predictor`` a name, or a dict of
+    ``PredictorConfig`` fields)."""
     from repro.core import predictors as pred_mod
     c = cfg["controller"]
     pc = c["predictor"]
@@ -71,7 +72,9 @@ def controller_kwargs(cfg: dict, mix: dict) -> dict:
         predictor=pred_mod.PredictorConfig(
             kind=pc["kind"], policy=pc["policy"],
             update_mode=pc["update_mode"], count_decay=pc["count_decay"],
-            warmup_steps=pc["warmup_steps"]),
+            warmup_steps=pc["warmup_steps"],
+            **({"ewma_alpha": pc["ewma_alpha"]} if "ewma_alpha" in pc
+               else {})),
         avail_predictor=ap,
         scheduler=mix.get("scheduler", "none"), **extra)
 

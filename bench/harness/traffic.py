@@ -19,6 +19,13 @@ would return before the program's own post-processing:
   its tenant class, ``"class": {"priority": p, "latency_target": l}``,
   and one that states none is priority 1, latency target 0.
 
+A ``mix`` or ``splice`` component is a bundled sample (``{"sample":
+file}``), a named scenario of the mix (``{"scenario": name}``, which is
+also a cell of its own), or an inline shape (``{"shape": s, "params":
+{...}}`` for ``s`` in ``INLINE_SHAPES``, or ``{"shape": "replay",
+"sample": file}``), drawn from the component's stream exactly as a named
+scenario of that entry would be, and no cell.
+
 Every scenario draws from ``default_rng([seed, crc32(salt)])`` exactly as
 the program salts its scenarios, so at a fixed seed the copies reproduce
 the program's generators bit for bit (``bench/tests/test_traffic.py``).
@@ -205,6 +212,8 @@ def _multi_parts(n: int, rng: np.random.Generator):
 
 
 _PARTS = {"flash_crowd": _flash_parts, "multi_tenant": _multi_parts}
+#: Shapes a mix or splice component may carry inline: one stream each.
+INLINE_SHAPES = ("bursty", "periodic", "ramp", "decay", "replay")
 
 
 def _child(rng: np.random.Generator) -> np.random.Generator:
@@ -238,16 +247,26 @@ class Generator:
         return np.random.default_rng([seed, zlib.crc32((base + salt).encode())])
 
     # -- workload ------------------------------------------------------------
-    def _component(self, comp: dict, n: int,
-                   rng: np.random.Generator) -> np.ndarray:
-        if "sample" in comp:
+    def _component(self, comp: dict, n: int, rng: np.random.Generator,
+                   where: str) -> np.ndarray:
+        """One component of a ``mix`` or ``splice``: an inline shape, a
+        bundled sample, or a named scenario, whose clipped trace it is.
+        An inline shape is drawn as a named scenario of that entry would
+        be; it is no scenario of the mix, so it adds no cell."""
+        if "shape" in comp:
+            if comp["shape"] not in INLINE_SHAPES:
+                raise ValueError(f"{where}: shape {comp['shape']!r} cannot "
+                                 f"be inline; inline shapes are "
+                                 f"{INLINE_SHAPES}")
+            e = comp
+        elif "sample" in comp:
             return replay(self.sample(comp["sample"]), n, rng)
-        # a scenario-name component is that scenario's clipped trace
-        return np.clip(np.asarray(self._shape(comp["scenario"], n, rng),
-                                  np.float32), 0.0, 1.0)
+        else:
+            e = self.scenarios[comp["scenario"]]
+        return np.clip(np.asarray(self._draw(e, n, rng, where), np.float32),
+                       0.0, 1.0)
 
-    def _shape(self, name: str, n: int, rng: np.random.Generator):
-        e = self.scenarios[name]
+    def _draw(self, e: dict, n: int, rng: np.random.Generator, where: str):
         shape = e["shape"]
         if shape == "bursty":
             return bursty(n, _sub_seed(rng), **e.get("params", {}))
@@ -272,9 +291,10 @@ class Generator:
             w = np.asarray(e["weights"], np.float64)
             w = w / w.sum()
             out = np.zeros(n, np.float64)
-            for wi, comp in zip(w, e["components"]):
-                out += wi * np.asarray(self._component(comp, n, _child(rng)),
-                                       np.float64)
+            for i, (wi, comp) in enumerate(zip(w, e["components"])):
+                out += wi * np.asarray(self._component(
+                    comp, n, _child(rng), f"{where} component {i}"),
+                    np.float64)
             return np.clip(out, 0.0, 1.0).astype(np.float32)
         if shape == "splice":
             f = np.asarray(e["fractions"], np.float64)
@@ -283,14 +303,15 @@ class Generator:
             edges = edges.astype(np.int64)
             edges[-1] = n
             segs = []
-            for comp, lo, hi in zip(e["components"], edges[:-1], edges[1:]):
+            for i, (comp, lo, hi) in enumerate(zip(e["components"],
+                                                   edges[:-1], edges[1:])):
                 child = _child(rng)
                 if hi > lo:
-                    segs.append(np.asarray(
-                        self._component(comp, int(hi - lo), child),
-                        np.float32))
+                    segs.append(np.asarray(self._component(
+                        comp, int(hi - lo), child,
+                        f"{where} component {i}"), np.float32))
             return np.clip(np.concatenate(segs), 0.0, 1.0)
-        raise ValueError(f"scenario {name!r}: unknown shape {shape!r}")
+        raise ValueError(f"{where}: unknown shape {shape!r}")
 
     def _tenants(self, name: str, n: int, rng: np.random.Generator):
         e = self.scenarios[name]
@@ -299,9 +320,10 @@ class Generator:
         if e["shape"] == "mix":
             w = np.asarray(e["weights"], np.float64)
             w = w / w.sum()
-            parts = np.stack([wi * np.asarray(
-                self._component(c, n, _child(rng)), np.float64)
-                for wi, c in zip(w, e["components"])])
+            parts = np.stack([wi * np.asarray(self._component(
+                c, n, _child(rng), f"scenario {name!r} component {i}"),
+                np.float64) for i, (wi, c) in enumerate(zip(
+                    w, e["components"]))])
             cls = [c.get("class", {}) for c in e["components"]]
             return parts, _spec([c.get("priority", 1.0) for c in cls],
                                 [c.get("latency_target", 0.0) for c in cls],
@@ -332,8 +354,9 @@ class Generator:
               tenants: bool) -> ScenarioTraffic:
         return ScenarioTraffic(
             name=name,
-            trace=np.asarray(self._shape(name, n_steps,
-                                         self.rng(name, seed))),
+            trace=np.asarray(self._draw(self.scenarios[name], n_steps,
+                                        self.rng(name, seed),
+                                        f"scenario {name!r}")),
             nodes=self._nodes(name, n_steps, self.rng(name, seed, "/nodes")),
             tenants=(self._tenants(name, n_steps, self.rng(name, seed))
                      if tenants else None))

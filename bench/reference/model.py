@@ -3,10 +3,10 @@
 Everything is derived from a configuration file (``bench/configs/``) and
 the generated traffic: the accelerator characterization (paper Table I and
 the delay/power library), the section V operating tables (a brute-force
-search of the voltage grid), the control loop (Markov predictor, bin
-selection, the ``headroom`` bump and its availability forecaster, the
-availability clamp, tenant scheduler) and the host-side reductions.  It
-imports nothing of the program.
+search of the voltage grid), the control loop (the Markov or EWMA
+workload predictor, bin selection, the ``headroom`` bump and its
+availability forecaster, the availability clamp, tenant scheduler) and
+the host-side reductions.  It imports nothing of the program.
 
 Arithmetic runs in a chosen type ``dt`` (the configuration states float32
 on the device) and the long sums in ``acc`` (float64 on the host, as the
@@ -404,6 +404,75 @@ def headroom_bump(tab: Dict[str, np.ndarray], reserve: np.ndarray,
 
 
 # ---------------------------------------------------------------------------
+# The workload predictor: a predict and an observe function per family
+# ---------------------------------------------------------------------------
+
+
+def markov_init(k: int, m: int, pc: dict, p: Prec) -> dict:
+    """Transition counts ``[K, M, M]`` (a 0.01 uniform prior plus the
+    identity) and each cell's current bin, 0 before any observation."""
+    counts = np.broadcast_to(p.c(0.01) * np.ones((m, m), p.dt)
+                             + np.eye(m, dtype=p.dt), (k, m, m)).copy()
+    return {"counts": counts, "cur": np.zeros(k, np.int64)}
+
+
+def markov_predict(state: dict, m: int, pc: dict, p: Prec) -> np.ndarray:
+    """The most counted successor of the current bin (first on ties)."""
+    rows = np.arange(state["cur"].size)
+    return np.argmax(state["counts"][rows, state["cur"]], axis=1)
+
+
+def markov_observe(state: dict, w_agg: np.ndarray, actual: np.ndarray,
+                   m: int, pc: dict, p: Prec) -> dict:
+    """Count the transition from the current bin to the observed one."""
+    rows = np.arange(actual.size)
+    state["counts"][rows, state["cur"], actual] += p.c(1.0)
+    state["cur"] = actual
+    return state
+
+
+def ewma_init(k: int, m: int, pc: dict, p: Prec) -> dict:
+    """The smoothed workload level, 1.0 (peak) before any evidence."""
+    return {"level": np.ones(k, p.dt)}
+
+
+def ewma_predict(state: dict, m: int, pc: dict, p: Prec) -> np.ndarray:
+    """The level's bin, ``floor(level · M)`` clipped to ``[0, M − 1]``."""
+    return np.clip(np.floor(state["level"] * p.c(m)), 0,
+                   m - 1).astype(np.int64)
+
+
+def ewma_observe(state: dict, w_agg: np.ndarray, actual: np.ndarray,
+                 m: int, pc: dict, p: Prec) -> dict:
+    """``level ← level + α · (w − level)`` on the aggregate workload."""
+    lvl = state["level"]
+    state["level"] = (lvl + p.c(pc["ewma_alpha"]) * (w_agg - lvl)).astype(
+        p.dt)
+    return state
+
+
+def workload_predictor(pc: dict):
+    """``(init, predict, observe)`` of the family the configuration's
+    ``predictor`` states, looked up by name when called, so a reference
+    module run over this one replaces a family by defining its
+    functions.  ``markov`` only with the paper's argmax policy, learning
+    on every step with no decay; ``ewma`` with its stated ``ewma_alpha``."""
+    kind = pc["kind"]
+    if kind == "markov":
+        if (pc.get("policy"), pc.get("update_mode"),
+                pc.get("count_decay")) != ("argmax", "always", 1.0):
+            raise ValueError(f"the reference's markov predictor is argmax, "
+                             f"always, no decay, not {pc!r}")
+        return markov_init, markov_predict, markov_observe
+    if kind == "ewma":
+        if "ewma_alpha" not in pc:
+            raise ValueError("the reference's ewma predictor needs the "
+                             "configuration to state ewma_alpha")
+        return ewma_init, ewma_predict, ewma_observe
+    raise ValueError(f"the reference has no predictor {kind!r}")
+
+
+# ---------------------------------------------------------------------------
 # The control loop over K independent cells
 # ---------------------------------------------------------------------------
 
@@ -424,7 +493,9 @@ def control_loop(tab: Dict[str, np.ndarray], w: np.ndarray, avail: np.ndarray,
     if bumps:
         a_warm = avail_warmup(ctl)
         a_last = np.full(k, n - 1, np.int64)
-    warm = ctl["predictor"]["warmup_steps"]
+    pc = ctl["predictor"]
+    warm = pc["warmup_steps"]
+    pred_init, pred_predict, pred_observe = workload_predictor(pc)
     margin_bins = int(np.floor(ctl["margin"] * m + 1e-9))
     on = scheduler != "none"
     use_prio = scheduler == "priority"
@@ -443,9 +514,7 @@ def control_loop(tab: Dict[str, np.ndarray], w: np.ndarray, avail: np.ndarray,
     eff_best = np.argmin(tab["power"] / np.maximum(tab["capacity"], eps),
                          axis=1)
 
-    counts = np.broadcast_to(p.c(0.01) * np.ones((m, m), p.dt)
-                             + np.eye(m, dtype=p.dt), (k, m, m)).copy()
-    cur = np.zeros(k, np.int64)
+    pstate = pred_init(k, m, pc, p)
     mispred = np.zeros(k, np.int64)
     margin_miss = np.zeros(k, np.int64)
     backlog = np.zeros((k, t), p.dt)
@@ -474,7 +543,7 @@ def control_loop(tab: Dict[str, np.ndarray], w: np.ndarray, avail: np.ndarray,
             if step < warm:
                 predicted = np.full(k, m - 1)
             else:
-                predicted = np.argmax(counts[rows, cur], axis=1)
+                predicted = pred_predict(pstate, m, pc, p)
             actual = np.clip(np.floor(w_agg * p.c(m)), 0, m - 1).astype(int)
             selected = predicted
             if on:
@@ -550,8 +619,7 @@ def control_loop(tab: Dict[str, np.ndarray], w: np.ndarray, avail: np.ndarray,
             scored = step >= warm
             mispred += (predicted != actual) & scored
             margin_miss += (actual > predicted + margin_bins) & scored
-            counts[rows, cur, actual] += c1
-            cur = actual
+            pstate = pred_observe(pstate, w_agg, actual, m, pc, p)
 
             part["power"] += pwr
             part["viol"] += violation.astype(p.dt)

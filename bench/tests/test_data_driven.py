@@ -158,3 +158,63 @@ def test_new_reference_module_and_mix_techniques_are_found_by_name(tmp_path):
 
     for p, data in before.items():
         assert p.read_bytes() == data, f"{p} was edited"
+
+
+def test_tiered_mix_of_inline_components_is_found_and_runs(tmp_path,
+                                                           compile_cache):
+    """A tiered deployment is data: a mix whose tenants are inline
+    components, each with its tier's class, run by the priority
+    scheduler, with its limits and a ``BENCHMARK.json`` entry, is found
+    by name and runs at a test's size with no other edit.  While the
+    program's scheduler lands one bin low, the run is made again with the
+    level computed as stated (``test_reference.retry_with_stated_level``)."""
+    from test_reference import retry_with_stated_level
+    root = _copy(tmp_path)
+    b = root / "bench"
+    before = {p: p.read_bytes() for p in b.rglob("*") if p.is_file()}
+
+    tiers = [  # ClusterData 2019 bands: production, mid, best-effort batch
+        ({"shape": "periodic", "params": {"period_max": 96,
+                                          "mean_load": 0.3, "burst": 0.1}},
+         200.0, 0.0),
+        ({"shape": "bursty", "params": {"mean_load": 0.4, "hurst": 0.8}},
+         117.0, 2.0),
+        ({"shape": "ramp", "params": {"lo": 0.1, "hi": 0.5, "noise": 0.05}},
+         112.0, 8.0)]
+    mix = {"about": "test", "tenants": "auto", "scheduler": "priority",
+           "scenarios": {"tiers": {
+               "shape": "mix", "weights": [0.5, 0.3, 0.2],
+               "components": [dict(c, **{"class": {
+                   "priority": p, "latency_target": lt}})
+                   for c, p, lt in tiers]}}}
+    (b / "traffic" / "tiers3.json").write_text(json.dumps(mix))
+    (b / "limits" / "fpga5-paper.tiers3.json").write_text(json.dumps(
+        {"limits": {"tables_power": 1e-3, "tables_volt": 0.0125,
+                    "power": 1e-3, "qos": 0.02, "served": 0.01,
+                    "backlog": 0.05, "mispred": 0.005, "tenant_qos": 0.02,
+                    "tenant_served": 0.01}}))
+    man = json.loads((root / "BENCHMARK.json").read_text())
+    man["workloads"].append({"name": "fpga5-paper.tiers3",
+                             "config": "fpga5-paper", "traffic": "tiers3",
+                             "chips": 1, "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(man))
+
+    run_cell = _module(b / "run_cell.py", "run_cell_copy3")
+    run_cell.CACHE_DIR = compile_cache
+    spec = run_cell.resolve("fpga5-paper.tiers3")
+    traffic = _module(b / "harness" / "traffic.py", "traffic_copy3")
+    (scen,) = traffic.generate(spec["mix"], 512, 5)
+    assert scen.tenants[0].shape == (3, 512)
+    assert scen.tenants[1]["priority"] == [200.0, 117.0, 112.0]
+    assert scen.tenants[1]["latency_target"] == [0.0, 2.0, 8.0]
+
+    res = retry_with_stated_level(
+        lambda: run_cell.run("fpga5-paper.tiers3", 2 ** 31 + 5, 0.0, False,
+                             require_chip=False, log=lambda m: None,
+                             sizes={"n_steps": 512, "chunk_size": 128}),
+        lambda r: not r["correct"])
+    assert res["correct"] is True, res["checks"]
+    assert {"tenant_qos", "tenant_served"} <= set(res["checks"])
+
+    for p, data in before.items():
+        assert p.read_bytes() == data, f"{p} was edited"

@@ -7,7 +7,10 @@ left out (their results taken from the other half); an answer altered
 where it is produced (one cell's power sum of one chunk 10 % high); in
 the cell whose mechanism is the ``headroom`` bump, the bump left out (it
 returns the selected bin) and its availability forecast one node high
-(as if it observed one usable node more).  An unbroken run passes.  The
+(as if it observed one usable node more); in the cell whose mechanism is
+the EWMA workload predictor, its level never updated and its smoothing
+weight 0.30 where the configuration states 0.35.  An unbroken run
+passes.  The
 four-chip cell runs on four virtual CPU devices in a process of its own,
 with one more fault: the exchange between chips left out (the second
 chip's cells come back with the first chip's results, as if its shard
@@ -15,6 +18,7 @@ were never gathered).
 """
 
 import contextlib
+import dataclasses
 import functools
 import json
 import os
@@ -122,6 +126,18 @@ def forecast_node_high(real):
     return forecast
 
 
+def ewma_level_frozen(real):
+    def observe_inner(self, cfg, inner, w, actual_bin, predicted_bin):
+        return inner
+    return observe_inner
+
+
+def ewma_alpha_low(real):
+    def observe_inner(self, cfg, *args):
+        return real(self, dataclasses.replace(cfg, ewma_alpha=0.30), *args)
+    return observe_inner
+
+
 FAULTS = {
     "state_unchanged": ("_control_step", state_unchanged),
     "bump_left_out": ("_headroom_bump", bump_left_out),
@@ -129,11 +145,17 @@ FAULTS = {
     "half_left_out": ("simulate_fleet_stream", half_left_out),
     "answer_altered": ("_fleet_stream_chunk_jit", answer_altered),
     "exchange_left_out": ("simulate_fleet_stream", exchange_left_out),
+    "ewma_level_frozen": ("pred_mod.EwmaPredictor.observe_inner",
+                          ewma_level_frozen),
+    "ewma_alpha_low": ("pred_mod.EwmaPredictor.observe_inner",
+                       ewma_alpha_low),
 }
 ONE_CHIP = ("state_unchanged", "half_left_out", "answer_altered")
 CASES = ([("fpga5-paper.aggregate", f) for f in ONE_CHIP]
          + [("fpga5-paper.failures-headroom", f)
             for f in ONE_CHIP + ("bump_left_out", "forecast_node_high")]
+         + [("fpga5-paper.predictor-ewma", f)
+            for f in ONE_CHIP + ("ewma_level_frozen", "ewma_alpha_low")]
          + [("compose-fpga5.quickstart", f) for f in ONE_CHIP])
 FOUR_CHIP = "compose-fpga5.quickstart-4chip"
 
@@ -155,6 +177,7 @@ def test_fault_is_not_correct(workload, fault):
 
 @pytest.mark.parametrize("workload", ["fpga5-paper.aggregate",
                                       "fpga5-paper.failures-headroom",
+                                      "fpga5-paper.predictor-ewma",
                                       "compose-fpga5.quickstart"])
 def test_unbroken_run_is_correct(workload):
     res = _run(workload)
@@ -185,6 +208,7 @@ def test_four_chip_fault_is_not_correct(fault):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     code = (f"import sys; sys.path[:0] = {paths!r}; "
+            f"import run_cell; run_cell.CACHE_DIR = {run_cell.CACHE_DIR!r}; "
             f"import test_faults; test_faults.run_four({fault!r})")
     p = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True, timeout=600)

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 import run_cell
+import test_faults
 from harness import check, program
 from harness import traffic as tg
 from reference import model
@@ -81,45 +82,64 @@ def _campaign_gaps(mix_name, n_steps=400, chunk=128):
     return check.gaps("campaign", stats, tabs, ref)
 
 
-@pytest.fixture
-def stated_division():
-    """The program with its provisioned level computed as stated."""
-    from repro.core import controller as ctl
-    from repro.core import scheduler as sched_mod
-    real = sched_mod.provision_bin
-    sched_mod.provision_bin = _stated_provision_bin
-    ctl._fleet_stream_chunk_jit.clear_cache()
-    yield
-    sched_mod.provision_bin = real
-    ctl._fleet_stream_chunk_jit.clear_cache()
+def stated_level():
+    """The program with its provisioned level computed as stated (the
+    scheduler's ``provision_bin`` replaced by the copy above)."""
+    return test_faults._patched("sched_mod.provision_bin",
+                                lambda real: _stated_provision_bin)
+
+
+def one_bin_low(real):
+    """``provision_bin`` as the program has it, one bin lower (at 0, 0)."""
+    import jax.numpy as jnp
+
+    def provision_bin(*args, **kw):
+        return jnp.maximum(real(*args, **kw) - 1, 0)
+    return provision_bin
+
+
+def _departures(g):
+    """The numbers by which the program departs from the reference
+    beyond rounding (none where the two compute the same)."""
+    tol = {"tables_power": 1e-5, "tables_volt": 0.0, "power": 1e-5}
+    return {k: v for k, v in g.items() if v > tol.get(k, 1e-6)}
 
 
 def _agree(g):
-    assert g["tables_power"] < 1e-5 and g["tables_volt"] == 0.0
-    assert g["power"] < 1e-5
-    for k in ("qos", "served", "backlog", "mispred"):
-        assert g[k] < 1e-6, (k, g[k])
-    if "tenant_qos" in g:
-        assert g["tenant_qos"] < 1e-6 and g["tenant_served"] < 1e-6
+    assert not _departures(g), _departures(g)
+
+
+def retry_with_stated_level(run, departs):
+    """``run()`` on the program as it stands; where ``departs`` of its
+    result, ``run()`` again with the scheduler's level computed as stated
+    (compiled, ``provision_bin`` lands one bin low where the level is a
+    multiple of 1 / 5: PERF.md, open questions).  Once the program
+    computes the level as stated, only the first run is made."""
+    out = run()
+    if departs(out):
+        with stated_level():
+            out = run()
+    return out
 
 
 @pytest.mark.parametrize("mix_name", ["aggregate", "tenants-priority",
-                                      "failures-headroom"])
-def test_campaign_agrees(mix_name, stated_division):
-    """On the tenant plane only with the level computed as the program
-    states it (see ``test_compiled_level_is_one_bin_low``)."""
-    _agree(_campaign_gaps(mix_name))
+                                      "failures-headroom", "predictor-ewma"])
+def test_campaign_agrees(mix_name):
+    """The program as it stands agrees with the reference; on the tenant
+    plane, where it does not, the program with the level as stated has
+    to."""
+    scheduled = _load("traffic", mix_name).get("scheduler", "none") != "none"
+    _agree(retry_with_stated_level(
+        lambda: _campaign_gaps(mix_name),
+        lambda g: scheduled and bool(_departures(g))))
 
 
-def test_compiled_level_is_one_bin_low():
-    """A fault of the program, witnessed here: compiled, the scheduler's
-    level ``(b + 1) / M`` becomes ``(b + 1) * (1 / M)``, and ``floor()``
-    lands one bin low where the level is a multiple of 1 / 5, so the
-    priority waterfill provisions below the stated maths and its power
-    departs from the reference (the tenant cell is left out of the
-    benchmark for it).  Fails once the program computes the level as
-    stated: the cell can then come back."""
-    g = _campaign_gaps("tenants-priority", n_steps=1024, chunk=1024)
+def test_planted_one_bin_low_departs():
+    """The check sees the tenant scheduler provision one bin low: with
+    that fault planted on top of ``provision_bin``, whatever it computes,
+    the priority waterfill's power and QoS depart from the reference."""
+    with test_faults._patched("sched_mod.provision_bin", one_bin_low):
+        g = _campaign_gaps("tenants-priority", n_steps=1024, chunk=1024)
     assert g["power"] > 1e-2 and g["qos"] > 1e-2, g
 
 
@@ -167,3 +187,23 @@ def test_composition_agrees():
 def test_usable_nodes_quantisation():
     frac = np.asarray([1.0, 0.875, 0.75, 0.5, 0.4, 0.1, 0.0])
     assert model.usable_nodes(frac, 7, 8).tolist() == [8, 4, 4, 4, 2, 1, 1]
+
+
+def test_reference_ewma_follows_the_stated_update():
+    """``level ← level + α · (w − level)`` from 1.0, predicting
+    ``floor(level · M)``; a family the reference does not have, or an
+    EWMA with no stated α, is refused."""
+    pc = {"kind": "ewma", "ewma_alpha": 0.35, "warmup_steps": 32}
+    init, predict, observe = model.workload_predictor(pc)
+    state = init(2, 25, pc, model.F32)
+    assert predict(state, 25, pc, model.F32).tolist() == [24, 24]
+    w = np.asarray([0.2, 0.9], np.float32)
+    state = observe(state, w, None, 25, pc, model.F32)
+    level = np.float32(1.0) + np.float32(0.35) * (w - np.float32(1.0))
+    assert np.array_equal(state["level"], level)
+    assert predict(state, 25, pc, model.F32).tolist() == [18, 24]
+    for bad in ({"kind": "ewma"}, {"kind": "holt_winters"},
+                {"kind": "markov", "policy": "quantile",
+                 "update_mode": "always", "count_decay": 1.0}):
+        with pytest.raises(ValueError):
+            model.workload_predictor(bad)

@@ -88,3 +88,57 @@ def test_mix_component_states_its_tenant_class():
     assert tiered.tenants[1]["share"] == plain.tenants[1]["share"]
     assert np.array_equal(tiered.tenants[0], plain.tenants[0])
     assert np.array_equal(tiered.trace, plain.trace)
+
+
+BURSE = {"shape": "bursty", "params": {"mean_load": 0.5, "hurst": 0.8}}
+INLINE = [BURSE,
+          {"shape": "periodic", "params": {"period_max": 64,
+                                           "mean_load": 0.3, "burst": 0.2}},
+          {"shape": "ramp", "params": {"lo": 0.1, "hi": 0.6, "noise": 0.05}},
+          {"shape": "decay", "params": {"peak": 0.6, "floor": 0.1,
+                                        "noise": 0.02}},
+          {"shape": "replay", "sample": "google_cluster.npz"}]
+
+
+@pytest.mark.parametrize("kind", ["mix", "splice"])
+def test_inline_component_draws_as_the_named_scenario(kind):
+    """A component that carries its shape inline draws bit for bit what
+    the same entry, named as a scenario of the mix and referred to by
+    name, draws in its place; the inline one adds no cell, and its
+    ``class`` is its tenant's."""
+    classes = [{"priority": float(5 - i), "latency_target": float(i)}
+               for i in range(len(INLINE))]
+    named_scen = {f"shape{i}": e for i, e in enumerate(INLINE)}
+    combo = {"shape": kind, "weights": [1.0] * len(INLINE),
+             "fractions": [1.0] * len(INLINE)}
+    named = {"scenarios": {**named_scen, "tiers": dict(combo, components=[
+        {"scenario": f"shape{i}", "class": c}
+        for i, c in enumerate(classes)])}, "tenants": "auto"}
+    inline = {"scenarios": {"tiers": dict(combo, components=[
+        dict(e, **{"class": c}) for e, c in zip(INLINE, classes)])},
+        "tenants": "auto"}
+    seed = 2 ** 31 + 99
+    by_name = tg.generate(named, 600, seed)[-1]
+    got = tg.generate(inline, 600, seed)
+    assert [g.name for g in got] == ["tiers"]
+    assert len(tg.generate(named, 600, seed)) == len(INLINE) + 1
+    assert got[0].trace.dtype == by_name.trace.dtype
+    assert np.array_equal(got[0].trace, by_name.trace)
+    if kind == "mix":
+        assert np.array_equal(got[0].tenants[0], by_name.tenants[0])
+        assert got[0].tenants[1] == by_name.tenants[1]
+        assert got[0].tenants[1]["priority"] == [5.0, 4.0, 3.0, 2.0, 1.0]
+
+
+@pytest.mark.parametrize("shape", ["mix", "splice", "flash_crowd",
+                                   "multi_tenant"])
+def test_inline_component_refuses_compound_shapes(shape):
+    """Mixes, splices and the shapes made of tenants are not inline; the
+    error names the component."""
+    mix = {"scenarios": {"tiers": {
+        "shape": "mix", "weights": [1.0, 1.0],
+        "components": [BURSE, {"shape": shape, "components": [BURSE],
+                               "weights": [1.0], "fractions": [1.0]}]}},
+        "tenants": None}
+    with pytest.raises(ValueError, match="scenario 'tiers' component 1"):
+        tg.generate(mix, 100, 1)
